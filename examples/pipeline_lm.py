@@ -34,7 +34,9 @@ def main():
 
     cfg = dataclasses.replace(cfg, num_layers=8)
     n_stages = 4
-    mesh = jax.make_mesh((n_stages,), ("stage",))
+    mesh = jax.make_mesh(
+        (n_stages,), ("stage",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     params = lm.init_model(cfg, jax.random.PRNGKey(0))
 
     # 1) the partitioner's stage plan (chain DP over per-layer costs)

@@ -5,6 +5,7 @@ failures surface early.  Results are cached as JSON files; re-running skips done
 cells.  Usage: python scripts/run_dryrun_sweep.py [outdir]
 """
 
+import os
 import subprocess
 import sys
 import time
@@ -25,6 +26,9 @@ def main():
         for shape in SHAPE_ORDER:
             for arch in ARCH_ORDER:
                 jobs.append((arch, shape, mp))
+    # each child compiles for 512 forced host devices: pin it to the CPU so
+    # that on a machine with an accelerator no child tries to open the chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     t0 = time.time()
     for i, (arch, shape, mp) in enumerate(jobs):
         tag = f"{arch}__{shape}__{'2x16x16' if mp else '16x16'}"
@@ -38,7 +42,7 @@ def main():
             cmd.append("--multi-pod")
         print(f"[{i+1}/{len(jobs)}] {tag}  (t={time.time()-t0:.0f}s)", flush=True)
         try:
-            subprocess.run(cmd, timeout=3000, check=False)
+            subprocess.run(cmd, timeout=3000, check=False, env=env)
         except subprocess.TimeoutExpired:
             (outdir / f"{tag}.json").write_text(
                 '{"arch": "%s", "shape": "%s", "mesh": "%s", '

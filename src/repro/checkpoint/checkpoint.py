@@ -100,7 +100,11 @@ def save(
         # ``latest`` untouched, still naming the previous complete step
         shutil.rmtree(tmp, ignore_errors=True)
         raise
-    (ckpt_dir / "latest").write_text(str(step))  # updated last: commit point
+    # updated last: the commit point.  Written aside and renamed over the old
+    # marker, so a concurrent reader never sees it empty or half-written.
+    marker_tmp = ckpt_dir / "latest.tmp"
+    marker_tmp.write_text(str(step))
+    os.replace(marker_tmp, ckpt_dir / "latest")
     _gc(ckpt_dir, keep)
     return final
 

@@ -20,14 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # JAX >= 0.7
-    shard_map = jax.shard_map
-    _SHMAP_NOCHECK = {"check_vma": False}
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-    _SHMAP_NOCHECK = {"check_rep": False}  # pre-0.7 spelling
-
 PyTree = Any
 
 
@@ -79,12 +71,12 @@ def gpipe_apply(
         return outs
 
     pspec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspec_params, P()),
         out_specs=P(),
-        **_SHMAP_NOCHECK,
+        check_vma=False,
     )(stage_params, x_micro)
 
 

@@ -433,7 +433,11 @@ class Program:
         ``StreamServer.recover(program, checkpoint_dir)``; device launches
         retry ``launch_retries`` times with exponential backoff from
         ``retry_base_s`` before the partition is quarantined and sessions
-        degrade to the all-host placement.
+        degrade to the all-host placement.  A launch that fails to compile
+        is not such a fault: each batch width compiles before any session
+        rides it, the one-lane launch here, so ``serve()`` raises
+        ``DeviceCompileError`` (a width first needed mid-service stops the
+        engine with it instead) and nothing degrades to the host.
         """
         from repro.serve_stream import StreamServer
 

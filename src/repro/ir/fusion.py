@@ -31,6 +31,7 @@ import numpy as np
 from repro.core.actor import Action, Actor, Port
 from repro.core.graph import GraphError
 from repro.kernels.stream_fused import StreamOp, StreamProgram, fold, fused_stream
+from repro.kernels.stream_fused.ops import pallas_tileable
 
 
 @dataclass
@@ -356,7 +357,7 @@ def build_fused(
     built = _try_stream_program(
         module, order, b_ins, b_outs, internal, opt_level=opt_level
     )
-    if built is not None:
+    if built is not None and pallas_tileable(built[0]):
         program, out_masks = built
 
         def vf(state, ins, _prog=program, _masks=tuple(out_masks)):
@@ -407,7 +408,7 @@ def build_fused(
     if codegen == "pallas":
         # expose the StreamProgram on the actor impl: the device runtime's
         # flat-megastep gate reads it to size (k, block) chunk stacks against
-        # the program's block_unit
+        # the program's transform_unit
         actor.stream_program = program
     return FusedBuild(
         actor=actor,

@@ -6,69 +6,89 @@ read of the input wire stack and one write of the output stack, instead of a
 round trip per actor.  The op list is static at trace time (it comes from the
 fusion pass), so the kernel body unrolls into straight-line VPU/MXU code.
 
-Layout: inputs are packed as a ``(n_in, N)`` float32 wire stack, outputs as
-``(n_out, N)``; the grid tiles the token axis.  ``matmul8`` reshapes the tile
-to ``(T/8, 8)`` and hits the MXU with the 8x8 basis; tiles are kept a
-multiple of 8 so block transforms never straddle a tile edge.
+Layout: the token axis is lane-dense.  A ``(n_in, ..., N)`` float32 wire
+stack is laid out as ``(n_in, rows, W)`` with ``W = row_width(program)`` — a
+multiple of the 128 TPU lanes and of every block transform's size — and the
+grid tiles the row axis in ``(rows_per_tile, W)`` tiles that satisfy the TPU
+(8, 128) block rule.  Because a row holds whole transform blocks, ``matmul8``
+and ``perm`` become ONE ``(tile, W) @ (W, W)`` block-diagonal matmul per tile
+(the 8x8 basis or the P-point one-hot repeated along the diagonal) instead of
+a per-block reshape, which the TPU compiler cannot lower.  Matmuls run at
+``precision=HIGHEST`` so float32 tokens are not rounded through bfloat16 and
+a one-hot ``perm`` stays exact.  Every op but ``matmul8`` is bitwise equal
+to the jnp reference; ``matmul8`` sums the same eight nonzero products of
+each output among zero terms.  On a v5e that matched XLA's 8-wide matmul
+bit for bit; in interpret mode on the CPU it agrees to float32 rounding.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.stream_fused.ops import block_unit as _block_unit
+from repro.core.graph import GraphError
+from repro.kernels.stream_fused.ops import row_width, transform_unit
 from repro.kernels.stream_fused.ref import apply_op
 
+_SUBLANES = 8
+# VMEM budget for one grid step: double-buffered input/output tiles plus the
+# live register file, kept well under the 16 MiB default scoped VMEM of v5e
+_TILE_BUDGET_BYTES = 4 << 20
 
-def _perm_matrix(idx) -> jnp.ndarray:
-    """A block reorder as a (P, P) one-hot matmul: y = x_blocks @ M with
-    M[idx[j], j] = 1.  Exactly one nonzero term per output lane, so the
-    matmul is bit-identical to the gather (x*1 plus exact-zero adds) while
-    staying MXU-shaped — Pallas TPU kernels cannot gather with an index
-    array, but they can matmul."""
-    import numpy as np
 
-    idx = np.asarray(idx)
-    m = np.zeros((len(idx), len(idx)), np.float32)
-    m[idx, np.arange(len(idx))] = 1.0
-    return jnp.asarray(m)
+def _block_matrix(op, width: int) -> np.ndarray:
+    """The (width, width) block-diagonal matrix applying ``op``'s block
+    transform to every whole block of a row: the 8x8 basis for ``matmul8``,
+    the one-hot ``M[idx[j], j] = 1`` for ``perm`` (exactly one nonzero term
+    per output, so the matmul reproduces the gather bit for bit).  The zero
+    weights mean a non-finite token turns its whole row into NaN."""
+    if op.kind == "matmul8":
+        m = np.asarray(op.params[0], np.float32)
+    else:
+        idx = np.asarray(op.params[0])
+        m = np.zeros((len(idx), len(idx)), np.float32)
+        m[idx, np.arange(len(idx))] = 1.0
+    return np.kron(np.eye(width // m.shape[0], dtype=np.float32), m)
 
 
 def _stream_kernel(x_ref, *rest, program):
-    # rest = (*matrix_refs, o_ref): matmul8 bases and perm one-hot matrices
+    # rest = (*matrix_refs, o_ref): the block-diagonal transform matrices
     # ride in as operands because Pallas kernels may not capture array
     # constants.
     matrix_refs, o_ref = rest[:-1], rest[-1]
     regs = [None] * program.n_regs
     for i in range(program.n_inputs):
-        regs[i] = x_ref[i, :]
+        regs[i] = x_ref[i]
     bi = 0
     for op in program.ops:
         if op.kind in ("matmul8", "perm"):
-            b = matrix_refs[bi][...]
+            regs[op.out] = jnp.dot(
+                regs[op.ins[0]], matrix_refs[bi][...],
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
             bi += 1
-            x = regs[op.ins[0]]
-            regs[op.out] = (x.reshape(-1, b.shape[0]) @ b).reshape(x.shape)
         else:
             regs[op.out] = apply_op(
                 op.kind, op.params, [regs[j] for j in op.ins]
             )
     for j, r in enumerate(program.outputs):
-        o_ref[j, :] = regs[r]
+        o_ref[j] = regs[r]
 
 
-def _tile(n: int, unit: int = 8, want: int = 512) -> int:
-    """Largest tile <= want that divides n and keeps block transforms whole."""
-    t = min(max(want, unit), n)
-    while n % t or t % unit:
-        t -= unit if t > unit else 1
-        if t <= unit:
-            return n if n % unit else unit
-    return t
+def _rows_per_tile(rows: int, width: int, program) -> int:
+    """Rows per grid step: the whole row axis when it fits the VMEM budget
+    (a block dim equal to the array dim is always legal), else the largest
+    multiple of 8 that does."""
+    live = 2 * (program.n_inputs + len(program.outputs)) + program.n_regs
+    fit = _TILE_BUDGET_BYTES // (4 * width * max(live, 1))
+    fit = max(_SUBLANES, fit - fit % _SUBLANES)
+    return rows if rows <= fit else fit
 
 
 def fused_stream_fwd(
@@ -79,36 +99,46 @@ def fused_stream_fwd(
 ) -> jax.Array:  # (n_out, N) / (n_out, B, N)
     """One Pallas launch per call, batched or not.
 
-    A ``(n_in, B, N)`` stack (B sessions' wires, one row each) is flattened to
-    ``(n_in, B*N)`` and run through the same grid — B sessions cost ONE kernel
-    launch, not B.  Every op is elementwise over the token axis except
-    ``matmul8``, whose 8-blocks stay inside a row when ``N % 8 == 0``, so each
-    row of the batched output is bit-identical to that row dispatched alone.
+    Leading axes between the wire axis and the token axis (B sessions, or
+    the k chunks of a flat megastep) fold into the row axis, so B rows cost
+    ONE kernel launch, not B.  Each row is padded with zeros to a multiple
+    of ``row_width(program)``; a block transform's blocks therefore never
+    straddle a row (or a session, or a chunk) as long as ``N`` is a
+    multiple of the transform's block size — otherwise the call is refused.
     """
-    if stack.ndim == 3:
-        n_in_b, b, n_b = stack.shape
-        out = fused_stream_fwd(
-            stack.reshape(n_in_b, b * n_b), program, interpret=interpret
+    n_in, *lead, n = stack.shape
+    unit = transform_unit(program)
+    if n % unit:
+        raise GraphError(
+            f"stream kernel: {n} tokens per row is not a multiple of the "
+            f"region's block transform size {unit}"
         )
-        return out.reshape(len(program.outputs), b, n_b)
-    n_in, n = stack.shape
-    t = _tile(n, _block_unit(program))
-    bases = []
-    for op in program.ops:
-        if op.kind == "matmul8":
-            bases.append(jnp.asarray(op.params[0], jnp.float32))
-        elif op.kind == "perm":
-            bases.append(_perm_matrix(op.params[0]))
-    return pl.pallas_call(
+    width = row_width(program)
+    n_pad = -n % width
+    if n_pad:
+        stack = jnp.pad(stack, [(0, 0)] * (stack.ndim - 1) + [(0, n_pad)])
+    rows = math.prod(lead) * (n + n_pad) // width
+    x = stack.reshape(n_in, rows, width)
+    tile = _rows_per_tile(rows, width, program)
+    r_pad = -rows % tile
+    if r_pad:
+        x = jnp.pad(x, ((0, 0), (0, r_pad), (0, 0)))
+    matrices = [
+        jnp.asarray(_block_matrix(op, width))
+        for op in program.ops if op.kind in ("matmul8", "perm")
+    ]
+    n_out = len(program.outputs)
+    out = pl.pallas_call(
         functools.partial(_stream_kernel, program=program),
-        grid=(n // t,),
-        in_specs=[pl.BlockSpec((n_in, t), lambda i: (0, i))]
-        + [
-            pl.BlockSpec(tuple(b.shape), lambda i: (0, 0)) for b in bases
-        ],
-        out_specs=pl.BlockSpec((len(program.outputs), t), lambda i: (0, i)),
+        grid=((rows + r_pad) // tile,),
+        in_specs=[pl.BlockSpec((n_in, tile, width), lambda i: (0, i, 0))]
+        + [pl.BlockSpec(m.shape, lambda i: (0, 0)) for m in matrices],
+        out_specs=pl.BlockSpec((n_out, tile, width), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (len(program.outputs), n), jnp.float32
+            (n_out, rows + r_pad, width), jnp.float32
         ),
         interpret=interpret,
-    )(stack, *bases)
+        name="stream_fused",
+    )(x, *matrices)
+    out = out[:, :rows].reshape(n_out, *lead, n + n_pad)
+    return out[..., :n] if n_pad else out

@@ -10,14 +10,14 @@ into one loop) — both compute the identical op sequence.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import List, Sequence, Tuple
-
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.stream_fused.ref import fused_stream_ref  # noqa: F401 — fused_stream_np re-exported for host-region callers
+from repro.kernels.stream_fused.ref import fused_stream_ref
 
 OP_KINDS = (
     "affine", "clip", "matmul8", "axpy", "const", "min2", "max2", "perm"
@@ -52,21 +52,40 @@ class StreamProgram:
         return f"stream({self.n_inputs} in, {self.n_regs} regs): {body} -> {outs}"
 
 
-def block_unit(program: StreamProgram) -> int:
-    """Token granule a tile (or a megastep chunk) must be a multiple of so
-    no block transform — ``matmul8``'s 8-blocks, ``perm``'s P-blocks — ever
-    straddles an edge.  The Pallas kernel sizes its grid tiles with this,
-    and the device runtime uses it to gate the *flat* megastep: a
-    ``(k, block)`` chunk stack may flatten into one ``k*block``-token launch
-    only when ``block % block_unit == 0``, which keeps every chunk's block
-    transforms whole and therefore bit-identical to k separate launches."""
-    import math
-
-    units = [8]
+def transform_unit(program: StreamProgram) -> int:
+    """Block size the program's block transforms need whole: the lcm of
+    ``matmul8``'s 8 and each ``perm``'s P (1 when it has none).  The device
+    runtime gates the *flat* megastep on it: a ``(k, block)`` chunk stack
+    may flatten into one ``k*block``-token launch only when ``block`` is a
+    multiple of it, which keeps every chunk's block transforms whole and
+    therefore bit-identical to k separate launches."""
+    units = [1]
     for op in program.ops:
-        if op.kind == "perm":
+        if op.kind == "matmul8":
+            units.append(8)
+        elif op.kind == "perm":
             units.append(len(op.params[0]))
     return math.lcm(*units)
+
+
+# TPU vector lanes: the Pallas kernel lays tokens out in rows of a multiple
+# of this, and applies block transforms as block-diagonal matmuls per row
+LANES = 128
+# widest row the kernel accepts — a perm whose size shares few factors with
+# 128 would otherwise need a huge block-diagonal matrix in VMEM
+MAX_ROW_WIDTH = 512
+
+
+def row_width(program: StreamProgram) -> int:
+    """Tokens per lane-dense row of the Pallas kernel: a multiple of the 128
+    lanes holding whole transform blocks."""
+    return math.lcm(LANES, transform_unit(program))
+
+
+def pallas_tileable(program: StreamProgram) -> bool:
+    """Whether the Pallas kernel can lay ``program`` out lane-dense (the
+    fusion pass picks the jnp codegen for regions it cannot)."""
+    return row_width(program) <= MAX_ROW_WIDTH
 
 
 def _on_cpu() -> bool:

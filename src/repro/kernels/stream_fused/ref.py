@@ -7,7 +7,7 @@ the fused region is verifiably equivalent to the per-actor device path:
 
   affine   (x + pre) * mul + post      identity components skipped exactly
   clip     jnp.clip(x, lo, hi)
-  matmul8  x.reshape(-1, 8) @ B        the 8-point block transform
+  matmul8  x.reshape(-1, 8) @ B        the 8-point block transform (HIGHEST)
   axpy     a + c * x                   one MAC tap
   const    jnp.full_like               rate seed (e.g. FIR acc = 0)
   min2/max2  jnp.minimum / jnp.maximum compare-exchange lanes
@@ -53,8 +53,13 @@ def apply_op(kind: str, params, ins: Sequence[jax.Array]) -> jax.Array:
         x = ins[0]
         # reshape back to the input's own shape so the op is polymorphic over
         # a leading batch axis ((B, N) wires — the multi-session server); for
-        # 1-D wires this is exactly the original reshape(-1)
-        return (x.reshape(-1, 8) @ jnp.asarray(basis)).reshape(x.shape)
+        # 1-D wires this is exactly the original reshape(-1).  HIGHEST keeps
+        # the float32 product exact on backends whose default precision
+        # rounds matmul inputs to bfloat16.
+        return jnp.matmul(
+            x.reshape(-1, 8), jnp.asarray(basis),
+            precision=jax.lax.Precision.HIGHEST,
+        ).reshape(x.shape)
     if kind == "axpy":
         (c,) = params
         x, a = ins

@@ -11,13 +11,20 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """A mesh whose axes are all ``Auto``: the sharding rules here place
+    arrays with ``with_sharding_constraint`` and let the compiler propagate,
+    which ``jax.make_mesh``'s default ``Explicit`` axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh() -> Mesh:
@@ -26,4 +33,4 @@ def make_test_mesh() -> Mesh:
     d = int(np.sqrt(n))
     while n % d:
         d -= 1
-    return jax.make_mesh((d, n // d), ("data", "model"))
+    return _auto_mesh((d, n // d), ("data", "model"))

@@ -102,6 +102,18 @@ class DeviceProgram:
             return self.megastep(state, inputs)
         return self.step(state, inputs)
 
+    def fresh_state(self) -> Dict[str, Any]:
+        """A new device copy of ``init_state`` for one launch chain.
+
+        The jitted entry points donate their state argument, and on an
+        accelerator donation deletes the buffer — so every chain (a PLink
+        per run, a ``DeviceStage`` per serve session) starts from its own
+        copy, and ``init_state`` itself is never handed to a launch."""
+        return jax.tree.map(
+            lambda x: x.copy() if isinstance(x, jax.Array) else x,
+            self.init_state,
+        )
+
     def batched_step(self, batch: int) -> Callable:
         """One jitted launch stepping ``batch`` independent session lanes.
 
@@ -266,25 +278,31 @@ def resolve_pe_device(pe: str):
     ``"cpu"``/``"gpu"``/``"tpu"`` (optionally ``":<index>"``) select the
     i-th device of that platform — with ``xla_force_host_platform_device_count``
     (or a real multi-chip host) different partitions land on different
-    devices and genuinely overlap.  Accelerator-model strings like
-    ``"tpu-v5e-16x16"`` bind to the default accelerator; host PEs
-    (``"x86_64"``) and anything unrecognized return None (default
-    placement), so a placement never fails just because this host lacks the
-    named hardware.
+    devices and genuinely overlap.  Such a PE names one device: when this
+    host has no device of that platform, or fewer than ``index + 1``, it
+    raises ``GraphError`` listing the devices present — a partition never
+    lands silently on another chip or platform.  Accelerator-model strings
+    like ``"tpu-v5e-16x16"`` (the XCF default) bind to the default device;
+    host PEs (``"x86_64"``) and anything unrecognized return None (default
+    placement).
     """
     if not pe:
         return None
     import re
 
     m = re.fullmatch(r"(cpu|gpu|tpu)(?::(\d+))?", pe.lower())
-    devices = jax.devices()
     if m is not None:
+        devices = jax.devices()
         same = [d for d in devices if d.platform == m.group(1)]
-        if same:
-            return same[int(m.group(2) or 0) % len(same)]
-        return devices[0] if devices else None
+        index = int(m.group(2) or 0)
+        if index >= len(same):
+            raise GraphError(
+                f"PE {pe!r} names {m.group(1)} device {index}, but this "
+                f"host has: {', '.join(str(d) for d in devices)}"
+            )
+        return same[index]
     if pe.lower().startswith(("tpu", "gpu", "accel")):
-        return devices[0] if devices else None
+        return jax.devices()[0]
     return None
 
 
@@ -514,15 +532,15 @@ def compile_partition(
         # flattens a (k, block) stack into one k*block-token grid launch),
         # so the megastep is literally ONE kernel launch with a k×-larger
         # grid — provided no block transform (matmul8 8-blocks, perm
-        # P-blocks) straddles a chunk edge, i.e. block % block_unit == 0.
-        from repro.kernels.stream_fused.ops import block_unit
+        # P-blocks) straddles a chunk edge, i.e. block % transform_unit == 0.
+        from repro.kernels.stream_fused.ops import transform_unit
 
         def _flat_ok(a: str) -> bool:
             prog_obj = getattr(impls[a], "stream_program", None)
             return (
                 module.actors[a].codegen == "pallas"
                 and prog_obj is not None
-                and block % block_unit(prog_obj) == 0
+                and block % transform_unit(prog_obj) == 0
             )
 
         flat = all(_flat_ok(a) for a in names)

@@ -153,7 +153,7 @@ class PLink:
         self.program = program
         self.env = env  # PortEnv: host FIFO endpoints for the boundary ports
         self.name = name
-        self.state = program.init_state
+        self.state = program.fresh_state()  # donated by the first launch
         self.stats = PLinkStats()
         self.k = max(1, program.megastep_k)
         # streamtrace: recorder captured once at construction — the invoke

@@ -31,6 +31,7 @@ from repro.serve_stream.recovery import RecoveryReport, SessionRecovery
 from repro.serve_stream.repartition import OnlineRepartitioner
 from repro.serve_stream.session import (
     AdmissionFull,
+    DeviceCompileError,
     ServeError,
     StreamSession,
 )
@@ -40,6 +41,7 @@ __all__ = [
     "AdmissionFull",
     "DeficitRoundRobin",
     "DeviceBatcher",
+    "DeviceCompileError",
     "OnlineRepartitioner",
     "RecoveryReport",
     "ServeError",

@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.serve_stream.session import DeviceStage
+from repro.serve_stream.session import DeviceCompileError, DeviceStage
 
 # A round may be padded with masked lanes up to this factor over the live
 # lane count when that reuses an already-compiled width — bounds wasted
@@ -110,12 +110,52 @@ class DeviceBatcher:
     # -- width selection ------------------------------------------------------
     def _width(self, live: int) -> int:
         """Smallest already-compiled width within ``LANE_SLACK`` of the live
-        lane count, else exactly the live count (and remember it)."""
+        lane count, else exactly the live count (compiled now)."""
         cap = min(math.ceil(live * LANE_SLACK), self.max_batch)
         reuse = [w for w in self._widths if live <= w <= cap]
-        w = min(reuse) if reuse else live
-        self._widths.add(w)
-        return w
+        if reuse:
+            return min(reuse)
+        self.prepare(live)
+        return live
+
+    def prepare(self, width: int = 1) -> None:
+        """Compile the ``width``-lane launch before any session rides it, by
+        launching one round of padding lanes (init state, all-False masks)
+        and waiting for it.  A compile or lowering failure raises
+        ``DeviceCompileError``: it is a property of the program, so the
+        engine never retries it, counts it as a fault or degrades around it.
+        Sequential mode launches one lane per session, so only width 1
+        exists there."""
+        width = 1 if self.mode == "sequential" else width
+        if width in self._widths:
+            return
+        program = self.program
+        try:
+            if self.mode == "sequential":
+                ins = self._on_device({
+                    k: (jnp.asarray(v), jnp.asarray(m))
+                    for k, (v, m) in self._pad().items()
+                })
+                out = program.launch(program.fresh_state(), ins)
+            else:
+                fn = (
+                    program.batched_megastep(width)
+                    if getattr(program, "megastep_k", 1) > 1
+                    else program.batched_step(width)
+                )
+                out = fn(
+                    program.stack_states([program.init_state] * width),
+                    self._on_device(
+                        program.pack_lanes([self._pad()] * width)
+                    ),
+                )
+            jax.block_until_ready(out)
+        except Exception as e:
+            raise DeviceCompileError(
+                f"device partition {self._track[len('batch:'):]!r}: the "
+                f"{width}-lane launch failed to compile: {e!r}"
+            ) from e
+        self._widths.add(width)
 
     def _pad(self) -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
         """The masked no-op payload one pad lane contributes: zeros with an
@@ -135,6 +175,15 @@ class DeviceBatcher:
                 for (a, p, dt) in self.program.in_ports
             }
         return self._pad_payload
+
+    def _on_device(self, tree):
+        """Inputs onto the partition's bound device.  A stateless partition
+        has no committed state to pin its launch, so without this every
+        lane would run on the default device whatever its PE names."""
+        device = self.program.device
+        if device is None or device is jax.devices()[0]:
+            return tree
+        return jax.device_put(tree, device)
 
     def _traced_dispatch(self, lanes: int, tokens_in: int, width: int) -> None:
         """Mirror one ``device_dispatched`` telemetry record into the trace
@@ -185,10 +234,10 @@ class DeviceBatcher:
             # per dispatch (payloads are (k, block) chunk stacks).
             for st, staged in zip(live, payloads):
                 tokens = sum(int(m.sum()) for _, m in staged.values())
-                ins = {
+                ins = self._on_device({
                     k: (jnp.asarray(v), jnp.asarray(m))
                     for k, (v, m) in staged.items()
-                }
+                })
                 state, outs, _idle = self.program.launch(st.state, ins)
                 st.state = state  # the donated chain: next launch feeds here
                 st.inflight += 1
@@ -205,7 +254,7 @@ class DeviceBatcher:
             states = [st.state for st in live]
             states += [self.program.init_state] * (width - len(live))
             state_b = self.program.stack_states(states)
-            ins_b = self.program.pack_lanes(padded)
+            ins_b = self._on_device(self.program.pack_lanes(padded))
             batched_fn = (
                 self.program.batched_megastep(width)
                 if getattr(self.program, "megastep_k", 1) > 1

@@ -44,6 +44,7 @@ from repro.runtime.scheduler import AdaptiveBackoff
 from repro.serve_stream.admission import DeficitRoundRobin
 from repro.serve_stream.batcher import DeviceBatcher
 from repro.serve_stream.session import (
+    DeviceCompileError,
     ServeError,
     SessionPipeline,
     StreamSession,
@@ -202,6 +203,9 @@ class StreamServer:
     def start(self) -> "StreamServer":
         if self._thread is not None:
             raise ServeError("server already started")
+        # the periodic checkpoint clock runs from engine start, not from
+        # construction (which includes compiling the device launches)
+        self._ckpt_last = time.perf_counter()
         self._thread = threading.Thread(
             target=self._engine_main, name="streamserve", daemon=True
         )
@@ -450,8 +454,10 @@ class StreamServer:
     def _make_batchers(self) -> Dict[str, DeviceBatcher]:
         """One independent ``DeviceBatcher`` per device partition — each
         lane keeps its own in-flight dispatches, so two accelerator
-        partitions pipeline against each other across all sessions."""
-        return {
+        partitions pipeline against each other across all sessions.  Each
+        compiles its one-lane launch here, so a program that cannot compile
+        for its device fails ``serve()`` (or the hot swap) itself."""
+        batchers = {
             pid: DeviceBatcher(
                 dp, mode=self.mode, max_batch=self.max_batch,
                 telemetry=self.telemetry, recorder=self.recorder,
@@ -459,6 +465,9 @@ class StreamServer:
             )
             for pid, dp in self._program.device_programs().items()
         }
+        for b in batchers.values():
+            b.prepare()
+        return batchers
 
     def _build_pipeline(
         self,
@@ -773,6 +782,8 @@ class StreamServer:
         for attempt in range(self.launch_retries + 1):
             try:
                 lanes = batcher.launch(stages)
+            except DeviceCompileError:
+                raise  # the program cannot run on its device: not a fault
             except Exception as e:  # noqa: PERF203 — the retry loop IS the point
                 self._c_faults.inc()
                 self._fault_instant(

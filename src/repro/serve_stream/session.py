@@ -50,6 +50,11 @@ class ServeError(RuntimeError):
     """Invalid use of the streaming server."""
 
 
+class DeviceCompileError(ServeError):
+    """A device partition's launch failed to compile or lower.  Raised to
+    the caller; never retried, counted as a fault or degraded to the host."""
+
+
 class AdmissionFull(ServeError):
     """Non-blocking submit against a full admission queue."""
 
@@ -235,7 +240,7 @@ class DeviceStage:
     def __init__(self, program, module: IRModule):
         self.program = program
         self.partition = getattr(program, "partition", "") or program.name
-        self.state = {a: dict(s) for a, s in program.init_state.items()}
+        self.state = program.fresh_state()  # donated by a sequential launch
         self.in_eps: Dict[str, ReaderEndpoint] = {}
         self.out_eps: Dict[str, WriterEndpoint] = {}
         # boundary ports grouped by destination actor; per-port granule =

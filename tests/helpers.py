@@ -44,14 +44,10 @@ except ImportError:  # degrade property tests to skips
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """jax.sharding.AbstractMesh across the signature change: newer jax takes
-    (axis_sizes, axis_names), 0.4.x takes ((name, size), ...) pairs."""
+    """A device-free ``jax.sharding.AbstractMesh`` for sharding-rule tests."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def lcg_values(n: int, mod: int = 100) -> List[int]:

@@ -176,6 +176,32 @@ def test_stream_kernel_matches_ref(n):
         )
 
 
+def _perm_program() -> StreamProgram:
+    from repro.apps.streams import _ZIGZAG_INV
+
+    ops = (
+        StreamOp("perm", (0,), 2, (_ZIGZAG_INV,)),
+        StreamOp("affine", (2,), 3, (-128.0, 0.125, 1.0)),
+        StreamOp("min2", (3, 1), 4),
+        StreamOp("clip", (4,), 5, (-2.0, 2.0)),
+    )
+    return StreamProgram(n_inputs=2, n_regs=6, ops=ops, outputs=(2, 5))
+
+
+@pytest.mark.parametrize("shape", [(64,), (4096,), (3, 192), (4, 1024)])
+def test_stream_kernel_bitwise_without_matmul8(shape):
+    """Every op but matmul8 is bitwise equal to the reference — the one-hot
+    perm matmul included — over plain, padded and batched wire shapes."""
+    rng = np.random.default_rng(2)
+    ins = [jnp.asarray(rng.normal(size=shape).astype(np.float32) * 300)
+           for _ in range(2)]
+    prog = _perm_program()
+    ref = fused_stream_ref(ins, prog)
+    pal = fused_stream(ins, prog, use="pallas")
+    for r, p in zip(ref, pal):
+        np.testing.assert_array_equal(np.asarray(p), np.asarray(r))
+
+
 def test_fold_preserves_values_and_shrinks():
     ops = (
         StreamOp("affine", (0,), 1, (0.0, 2.0, 1.0)),

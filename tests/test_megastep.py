@@ -210,6 +210,54 @@ def test_donated_state_is_never_read_after_donation():
     assert np.asarray(jax.tree.leaves(st2)[0]).shape == ()
 
 
+def test_each_launch_chain_starts_from_its_own_state_copy():
+    """The donated chain never reuses a tree: a second run() and every new
+    serve session start from their own device copy of ``init_state``, and
+    no state leaf is ever handed to two launches (on an accelerator the
+    first launch deletes what it was given)."""
+    g, got, xcf = _chain_graph(n_tok=300, stateful=True)
+    p = repro.compile(g, xcf, block=BLOCK)
+    (prog,) = p.device_programs().values()
+    init_leaves = jax.tree.leaves(prog.init_state)
+    assert init_leaves and all(isinstance(x, jax.Array) for x in init_leaves)
+    handed = []  # state leaves of every launch, kept alive so ids stay unique
+    step = prog.step
+
+    def recording_step(state, ins):
+        handed.append(jax.tree.leaves(state))
+        return step(state, ins)
+
+    prog.step = recording_step
+    p.run()
+    first = list(got)
+    first_launch_of_run2 = len(handed)
+    got.clear()
+    p.run()
+    assert got == first
+    assert first_launch_of_run2 < len(handed)
+    run_leaves = [leaf for leaves in handed for leaf in leaves]
+    server = p.serve(batching=False)  # sequential lanes donate like PLink
+    sessions = [server.open_session() for _ in range(2)]
+    stage_leaves = [
+        leaf for s in sessions for st in s.pipeline.stages.values()
+        for leaf in jax.tree.leaves(st.state)
+    ]
+    stream = [float(i % 7 - 3) for i in range(300)]
+    with server:
+        for s in sessions:
+            s.submit(stream)
+            s.close()
+        assert server.drain(timeout=120)
+    assert all(s.output() == first for s in sessions)
+
+    launched = [leaf for leaves in handed for leaf in leaves]
+    assert len({id(x) for x in launched}) == len(launched)
+    for leaf in launched + stage_leaves:
+        assert not any(leaf is x for x in init_leaves)
+    assert len({id(x) for x in stage_leaves}) == len(stage_leaves)
+    assert not {id(x) for x in stage_leaves} & {id(x) for x in run_leaves}
+
+
 def test_plink_retire_does_not_touch_state():
     """PLink updates self.state at LAUNCH time (to the async state future)
     and _retire takes only (outs, idle) — writing state at retirement would

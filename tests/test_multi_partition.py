@@ -155,6 +155,14 @@ def test_resolve_pe_device():
     assert resolve_pe_device("tpu-v5e-16x16") is default
     plat = default.platform
     assert resolve_pe_device(f"{plat}:0") is default
+    # a PE naming an absent device raises, listing what is present — no
+    # modulo wrap onto another chip, no fallback to another platform
+    n = len([d for d in jax.devices() if d.platform == plat])
+    with pytest.raises(GraphError, match=f"{plat} device {n}.*{default}"):
+        resolve_pe_device(f"{plat}:{n}")
+    missing = "gpu" if plat != "gpu" else "tpu"
+    with pytest.raises(GraphError, match=f"{missing} device 0"):
+        resolve_pe_device(missing)
     # compiled programs carry the binding
     net, _ = NETWORKS["IDCT8"](8)
     prog = repro.compile(net, backend="device", block=BLOCK)

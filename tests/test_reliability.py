@@ -6,6 +6,7 @@ per-session blast-radius isolation, and the checkpoint-layer hardening
 
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -14,7 +15,7 @@ from repro import checkpoint as ckpt
 from repro.apps.streams import NETWORKS
 from repro.checkpoint import AsyncCheckpointer
 from repro.runtime import chaos
-from repro.serve_stream import ServeError, StreamServer
+from repro.serve_stream import DeviceCompileError, ServeError, StreamServer
 
 BLOCK = 256
 
@@ -343,6 +344,65 @@ def test_persistent_launch_failure_degrades_to_host():
         assert server.telemetry.lifetime().swaps == 1
 
 
+def _break_launches(monkeypatch, min_lanes):
+    """Make every batched launch of ``min_lanes`` or more lanes fail the
+    way a kernel that cannot lower does: at its first call, with no token
+    staged into it yet."""
+    from repro.runtime.device_runtime import DeviceProgram
+
+    def broken(orig):
+        def get(self, batch):
+            fn = orig(self, batch)
+
+            def call(state, ins):
+                if jax.tree.leaves(ins)[0].shape[0] >= min_lanes:
+                    raise ValueError("Mosaic failed to compile TPU kernel")
+                return fn(state, ins)
+
+            return call
+
+        return get
+
+    for name in ("batched_step", "batched_megastep"):
+        monkeypatch.setattr(
+            DeviceProgram, name, broken(getattr(DeviceProgram, name))
+        )
+
+
+def test_compile_error_fails_serve_and_never_degrades(monkeypatch):
+    """A device launch that cannot compile fails serve() itself: it is not
+    a launch fault, so it is never retried, counted or degraded to host."""
+    _break_launches(monkeypatch, min_lanes=1)
+    prog = _compiled("TopFilter", 1200)
+    with pytest.raises(DeviceCompileError, match="failed to compile"):
+        prog.serve()
+
+
+def test_compile_error_at_a_new_batch_width_stops_the_engine(monkeypatch):
+    """A width first needed mid-service compiles before any session rides
+    it; when that fails the engine stops and every client sees the error —
+    the sessions are not carried on to the host placement."""
+    _break_launches(monkeypatch, min_lanes=2)
+    stream, _ref = _reference("TopFilter", 1200)
+    server = _compiled("TopFilter", 1200).serve()  # width 1 compiles fine
+    sessions = [server.open_session() for _ in range(3)]
+    for s in sessions:
+        s.submit(stream)
+        s.close()
+    server.start()  # the first round has three ready lanes
+    with pytest.raises(ServeError, match="failed to compile"):
+        server.drain(timeout=120)
+    with pytest.raises(DeviceCompileError):
+        server.stop()
+    for s in sessions:
+        with pytest.raises(ServeError):
+            s.output()
+    assert server._c_faults.value == 0
+    assert server._g_degraded.value == 0
+    assert not server._quarantined
+    assert server.program.hw_partition is not None  # never swapped to host
+
+
 def test_lane_death_mid_service_degrades_and_completes():
     """The PLink-site variant: the lane dies after some healthy launches
     (tokens already flowed through the device), then every later launch
@@ -429,6 +489,35 @@ def test_torn_checkpoint_write_is_invisible(tmp_path):
         assert not list(tmp_path.glob(".tmp_*"))    # no litter
     restored, _ = ckpt.restore(tmp_path, 1, tree)
     np.testing.assert_array_equal(np.asarray(restored["a"]), tree["a"])
+
+
+def test_latest_marker_is_never_read_half_written(tmp_path):
+    """The ``latest`` commit marker is replaced atomically: a reader polling
+    it while saves land (the periodic serve checkpoint) never sees it empty,
+    and the step it names only moves forward."""
+    import threading
+
+    tree = {"a": np.arange(4, dtype=np.float32)}
+    n_saves = 300
+    done = threading.Event()
+
+    def writer():
+        try:
+            for step in range(1, n_saves + 1):
+                ckpt.save(tmp_path, step, tree)
+        finally:
+            done.set()
+
+    t = threading.Thread(target=writer)
+    t.start()
+    seen = []
+    while not done.is_set():
+        step = ckpt.latest_step(tmp_path)  # raised on an empty marker
+        if step is not None:
+            seen.append(step)
+    t.join()
+    assert seen == sorted(seen)
+    assert ckpt.latest_step(tmp_path) == n_saves
 
 
 def test_async_checkpointer_surfaces_background_error(tmp_path):

@@ -19,12 +19,15 @@ SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     out = {}
+
+    def auto_mesh(shape, axes):
+        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
     # ---- gpipe vs sequential ----
     from repro.distributed.pipeline import gpipe_apply, stack_stage_params
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = auto_mesh((4,), ("stage",))
     key = jax.random.PRNGKey(0)
     per_stage = []
     for i in range(4):
@@ -44,17 +47,11 @@ SCRIPT = textwrap.dedent(
 
     # ---- int8 all-reduce over an axis ----
     from repro.distributed.compression import all_reduce_int8
-    try:
-        shard_map = jax.shard_map
-        nocheck = {"check_vma": False}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        nocheck = {"check_rep": False}
-    mesh2 = jax.make_mesh((8,), ("d",))
+    mesh2 = auto_mesh((8,), ("d",))
     y = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 128))
-    f = shard_map(lambda a: all_reduce_int8(a[0], "d")[None],
-                  mesh=mesh2, in_specs=P("d"), out_specs=P("d"),
-                  **nocheck)
+    f = jax.shard_map(lambda a: all_reduce_int8(a[0], "d")[None],
+                      mesh=mesh2, in_specs=P("d"), out_specs=P("d"),
+                      check_vma=False)
     with mesh2:
         red = f(y)
     true = jnp.sum(y, 0, keepdims=True)
@@ -69,7 +66,7 @@ SCRIPT = textwrap.dedent(
     from repro.optim import OptConfig, init_opt_state
     cfg = get_config("smollm-135m").reduced()
     opt = OptConfig()
-    mesh3 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh3 = auto_mesh((2, 4), ("data", "model"))
     rules = make_rules(cfg, mesh3)
     key = jax.random.PRNGKey(0)
     params = lm.init_model(cfg, key)

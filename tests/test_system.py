@@ -71,9 +71,9 @@ def test_hlo_analysis_collectives_on_spmd_program():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze
-        mesh = jax.make_mesh((4,), ("d",))
+        mesh = jax.make_mesh((4,), ("d",), axis_types=(AxisType.Auto,))
         x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
         sh = NamedSharding(mesh, P("d", None))
         def f(a):
