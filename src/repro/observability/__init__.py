@@ -6,7 +6,9 @@ One recorder, three views (see docs/observability.md):
      .trace()`` export Trace Event Format JSON that opens in
      ``chrome://tracing`` / Perfetto: one track per scheduler thread,
      PLink lane, and serve session; spans for actor firings, host-fused
-     region evaluations, and the PLink stage/dispatch/sync/retire phases.
+     region evaluations, and the PLink stage/dispatch/sync/retire phases;
+     a serving engine's round phases (``span``) land in the JAX
+     profiler's trace too, beside the device's operations.
   2. **Metrics** — ``MetricsRegistry`` counters/gauges/histograms
      (p50/p95/p99) backing the serve engine's TTFO and inter-block
      latency SLOs, with Prometheus text exposition.
@@ -28,7 +30,12 @@ from repro.observability.metrics import (
     Histogram,
     MetricsRegistry,
 )
-from repro.observability.recorder import TraceRecorder, activate, current
+from repro.observability.recorder import (
+    TraceRecorder,
+    activate,
+    current,
+    span,
+)
 from repro.observability.trace_profile import (
     authored_channel_key,
     phase_totals,
@@ -48,6 +55,7 @@ __all__ = [
     "load_trace",
     "phase_totals",
     "snapshot_from_trace",
+    "span",
     "validate_chrome_trace",
     "write_chrome_trace",
 ]

@@ -31,6 +31,12 @@ Event model (one tuple per event)::
 lane the event renders on — one per scheduler thread, PLink lane, or
 serve session — and becomes a Chrome ``tid`` with a ``thread_name``
 metadata record.
+
+``span`` is the one instrumentation entry point for a timed phase: it
+opens a ``jax.profiler.TraceAnnotation`` named ``repro.<layer>.<name>`` (an
+event on the profiler's own clock, beside the device's operations, when a
+profiler session records) and, given a recorder, records the same phase as
+a complete span.
 """
 
 from __future__ import annotations
@@ -38,6 +44,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 Event = Tuple[str, str, str, str, int, int, Optional[dict]]
 
@@ -163,6 +171,63 @@ class TraceRecorder:
     def total_events(self) -> int:
         with self._reg_lock:
             return sum(min(b.head, b.capacity) for b in self._buffers)
+
+
+class span:
+    """One timed phase, in the profiler's trace and in a recorder.
+
+    ``with span(rec, track, layer, name, **args) as sp:`` opens
+    ``TraceAnnotation(f"repro.{layer}.{name}", **args)`` on entry and, when
+    ``rec`` is not None, records ``rec.complete(track, name, cat, t0, dur,
+    args)`` on exit (``cat`` defaults to ``layer``).  The site may add to
+    ``sp.args`` before exit: those reach the recorder, while the profiler
+    event carries the args given at entry.  ``sp.t0_ns`` is the span's start
+    (``perf_counter_ns``), ``sp.dur_ns`` its duration once it has exited,
+    and ``sp.discard()`` leaves no record (the profiler event stands).
+
+    With no recorder and no profiler session running, the whole cost is the
+    annotation's enter and exit.
+    """
+
+    __slots__ = (
+        "recorder", "track", "name", "cat", "args", "t0_ns", "dur_ns",
+        "_note",
+    )
+
+    def __init__(
+        self,
+        recorder: Optional[TraceRecorder],
+        track: str,
+        layer: str,
+        name: str,
+        cat: Optional[str] = None,
+        **args,
+    ):
+        self.recorder = recorder
+        self.track = track
+        self.name = name
+        self.cat = cat or layer
+        self.args = args
+        self.dur_ns = 0
+        self._note = TraceAnnotation(f"repro.{layer}.{name}", **args)
+
+    def discard(self) -> None:
+        """Record nothing into the recorder on exit."""
+        self.recorder = None
+
+    def __enter__(self) -> "span":
+        self._note.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        self._note.__exit__(*exc)
+        if self.recorder is not None:
+            self.recorder.complete(
+                self.track, self.name, self.cat, self.t0_ns, self.dur_ns,
+                self.args or None,
+            )
 
 
 # ---------------------------------------------------------------------------

@@ -17,14 +17,16 @@ see docs/observability.md for the full schema):
   cat ``actor``    X-span per actor-machine invoke; ``args.fires``.
   cat ``plink``    X-span per launch phase, name in stage/dispatch/sync/
                    retire, on a ``lane:*`` track; ``args.tokens``/``k``.
-  cat ``device``   serve-mode batched lanes: ``dispatch`` events carry
-                   ``args.lanes``/``tokens_in``; ``retire`` spans carry
-                   ``args.tokens_out``/``time_ns`` — the *same numbers* the
-                   batcher feeds live telemetry, so replay is exact.
+  cat ``device``   serve-mode batched lanes: ``launch`` spans carry
+                   ``args.lanes``/``tokens_in``/``width``; ``retire`` spans
+                   carry ``args.tokens_out``/``time_ns`` — the *same
+                   numbers* the batcher feeds live telemetry, so replay is
+                   exact.
   cat ``channel``  C-counters named ``src.sp->dst.dp`` whose args carry the
                    authored endpoints and whose value is a token delta.
   cat ``session``  lifecycle instants (open/close/submit) on session tracks.
-  cat ``engine``   hot-swap instants.
+  cat ``engine``   round-phase spans (``pump`` carries ``args.tokens``, the
+                   tokens it moved) and hot-swap instants.
 """
 
 from __future__ import annotations
@@ -116,6 +118,7 @@ def snapshot_from_trace(
     device_time_ns = 0
     tok_in = tok_out = 0
     opened = closed = chunks = split = submitted = delivered = swaps = 0
+    pumped = 0
     queue_peak = 0
     t_lo: Optional[float] = None
     t_hi = 0.0
@@ -147,9 +150,10 @@ def snapshot_from_trace(
                     channel_tokens.get(key, 0) + int(args["value"])
                 )
         elif cat == "device":
-            if ev["name"] == "dispatch":
+            # a launch that raised carries no lanes: it dispatched nothing
+            if ev["name"] == "launch" and "lanes" in args:
                 dispatches += 1
-                ln = int(args.get("lanes", 1))
+                ln = int(args["lanes"])
                 lanes += ln
                 lanes_peak = max(lanes_peak, ln)
                 width += int(args.get("width", 0)) or ln
@@ -183,8 +187,11 @@ def snapshot_from_trace(
                 queue_peak = max(queue_peak, int(args.get("queued", 0)))
             elif ev["name"] == "deliver":
                 delivered += int(args.get("tokens", 0))
-        elif cat == "engine" and ev["name"] == "hot_swap":
-            swaps += 1
+        elif cat == "engine":
+            if ev["name"] == "hot_swap":
+                swaps += 1
+            elif ev["name"] == "pump":
+                pumped += int(args.get("tokens", 0))
 
     if seconds is None:
         seconds = 0.0 if t_lo is None else max(t_hi - t_lo, 0.0) / 1e6
@@ -208,4 +215,5 @@ def snapshot_from_trace(
         tokens_delivered=delivered,
         queue_peak=queue_peak,
         swaps=swaps,
+        tokens_pumped=pumped,
     )

@@ -69,12 +69,18 @@ class AdaptiveBackoff:
         self._n = 0
 
     def next_timeout(self) -> float:
-        """The wait budget for the next poll (0.0 while still spinning)."""
+        """The wait budget for the next poll (0.0 while still spinning).
+        The ramp stops growing once it reaches ``cap``: every later poll
+        waits ``cap``, however long no progress comes."""
         n = self._n
-        self._n += 1
         if n < self.spins:
+            self._n = n + 1
             return 0.0
-        return min(self.first * (2.0 ** (n - self.spins)), self.cap)
+        t = self.first * 2.0 ** (n - self.spins)
+        if t >= self.cap:
+            return self.cap
+        self._n = n + 1
+        return t
 
     def pause(self) -> None:
         """Sleep for the next budget (single-threaded waiters)."""

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.observability.recorder import span
 from repro.serve_stream.session import DeviceCompileError, DeviceStage
 
 # A round may be padded with masked lanes up to this factor over the live
@@ -185,27 +186,31 @@ class DeviceBatcher:
             return tree
         return jax.device_put(tree, device)
 
-    def _traced_dispatch(self, lanes: int, tokens_in: int, width: int) -> None:
-        """Mirror one ``device_dispatched`` telemetry record into the trace
-        (same lanes/token counts, so replay is exact)."""
+    def _dispatched(self, sp: span, lanes: int, tokens_in: int,
+                    width: int) -> None:
+        """Feed one dispatch to telemetry and the same numbers to the
+        launch span's args, so replay is exact."""
         if self.telemetry is not None:
             self.telemetry.device_dispatched(lanes, tokens_in, width=width)
-        if self.recorder is not None:
-            self.recorder.instant(
-                self._track, "dispatch", "device",
-                {"lanes": lanes, "tokens_in": tokens_in, "width": width},
-            )
+        sp.args.update(lanes=lanes, tokens_in=tokens_in, width=width)
+
+    def _span(self, name: str, round_no: int) -> span:
+        return span(
+            self.recorder, self._track, "batcher", name, cat="device",
+            round=round_no,
+        )
 
     # -- launch --------------------------------------------------------------
     def can_launch(self) -> bool:
         return len(self.inflight) < self.depth
 
-    def launch(self, stages: List[DeviceStage]) -> int:
+    def launch(self, stages: List[DeviceStage], round_no: int = 0) -> int:
         """Dispatch one round over up to ``max_batch`` of ``stages`` (in the
         given order — the engine's fairness ordering); returns lanes
         launched.  Stages already riding an earlier round may join: their
         state is the previous round's output future and XLA serializes the
-        launches through it."""
+        launches through it.  ``round_no`` is the engine round the launch
+        span belongs to."""
         if self.chaos is not None:
             # chaos site BEFORE any staging: an injected launch failure
             # leaves every FIFO and stage untouched, so the engine's
@@ -216,36 +221,22 @@ class DeviceBatcher:
                 + (getattr(self.program, "partition", "")
                    or self.program.name)
             )
-        payloads = []
-        live: List[DeviceStage] = []
-        for st in stages:
-            if len(live) >= self.max_batch:
-                break
-            staged = st.stage()
-            if staged is not None:
-                payloads.append(staged)
-                live.append(st)
-        if not live:
-            return 0
-        t0 = time.perf_counter_ns()
         if self.mode == "sequential":
-            # one dispatch per session — the per-session baseline.  launch()
-            # routes to the megastep when the program runs k>1 iterations
-            # per dispatch (payloads are (k, block) chunk stacks).
-            for st, staged in zip(live, payloads):
-                tokens = sum(int(m.sum()) for _, m in staged.values())
-                ins = self._on_device({
-                    k: (jnp.asarray(v), jnp.asarray(m))
-                    for k, (v, m) in staged.items()
-                })
-                state, outs, _idle = self.program.launch(st.state, ins)
-                st.state = state  # the donated chain: next launch feeds here
-                st.inflight += 1
-                self.inflight.append(
-                    _Round([st], outs, width=1, batched=False)
-                )
-                self._traced_dispatch(1, tokens, width=1)
-        else:
+            return self._launch_sequential(stages, round_no)
+        with self._span("launch", round_no) as sp:
+            payloads = []
+            live: List[DeviceStage] = []
+            for st in stages:
+                if len(live) >= self.max_batch:
+                    break
+                staged = st.stage()
+                if staged is not None:
+                    payloads.append(staged)
+                    live.append(st)
+            if not live:
+                sp.discard()
+                return 0
+            t0 = time.perf_counter_ns()
             tokens = sum(
                 int(m.sum()) for p in payloads for _, m in p.values()
             )
@@ -266,20 +257,46 @@ class DeviceBatcher:
                 # can ride the NEXT round before this one retires
                 st.state = self.program.unstack_state(state_b, lane)
                 st.inflight += 1
-            self.inflight.append(
-                _Round(live, outs, width=width, batched=True)
-            )
-            self._traced_dispatch(len(live), tokens, width=width)
-        dt = time.perf_counter_ns() - t0
-        new = self.inflight[-1:] if self.mode != "sequential" else (
-            self.inflight[-len(live):]
-        )
-        for entry in new:  # split the call's wall time across its dispatches
-            entry.t_launch_ns = dt // len(new)
+            entry = _Round(live, outs, width=width, batched=True)
+            self.inflight.append(entry)
+            self._dispatched(sp, len(live), tokens, width)
+            entry.t_launch_ns = time.perf_counter_ns() - t0
         return len(live)
 
+    def _launch_sequential(
+        self, stages: List[DeviceStage], round_no: int
+    ) -> int:
+        """One dispatch, and one launch span, per session — the per-session
+        baseline.  ``program.launch`` routes to the megastep when the
+        program runs k>1 iterations per dispatch (payloads are (k, block)
+        chunk stacks)."""
+        launched = 0
+        for st in stages:
+            if launched >= self.max_batch:
+                break
+            with self._span("launch", round_no) as sp:
+                staged = st.stage()
+                if staged is None:
+                    sp.discard()
+                    continue
+                t0 = time.perf_counter_ns()
+                tokens = sum(int(m.sum()) for _, m in staged.values())
+                ins = self._on_device({
+                    k: (jnp.asarray(v), jnp.asarray(m))
+                    for k, (v, m) in staged.items()
+                })
+                state, outs, _idle = self.program.launch(st.state, ins)
+                st.state = state  # the donated chain: next launch feeds here
+                st.inflight += 1
+                entry = _Round([st], outs, width=1, batched=False)
+                self.inflight.append(entry)
+                self._dispatched(sp, 1, tokens, 1)
+                entry.t_launch_ns = time.perf_counter_ns() - t0
+            launched += 1
+        return launched
+
     # -- retire --------------------------------------------------------------
-    def poll(self, block: bool = False) -> int:
+    def poll(self, block: bool = False, round_no: int = 0) -> int:
         """Retire completed rounds (oldest first, preserving per-session
         order); ``block=True`` forces the oldest to completion.  Returns
         tokens moved back into host FIFOs."""
@@ -288,41 +305,36 @@ class DeviceBatcher:
             head = self.inflight[0]
             if not block and not _tree_ready(head.outs):
                 break
-            moved += self._retire(head)
+            moved += self._retire(head, round_no)
             self.inflight.pop(0)
             block = False  # only force the oldest
         return moved
 
-    def _retire(self, entry: _Round) -> int:
-        t0 = time.perf_counter_ns()
-        moved = 0
-        if entry.batched:
-            outs_np = {
-                k: (np.asarray(v), np.asarray(m))
-                for k, (v, m) in entry.outs.items()
-            }
-            for lane, st in enumerate(entry.riders):
-                lane_outs = {
-                    k: (v[lane], m[lane]) for k, (v, m) in outs_np.items()
+    def _retire(self, entry: _Round, round_no: int) -> int:
+        with self._span("retire", round_no) as sp:
+            moved = 0
+            if entry.batched:
+                outs_np = {
+                    k: (np.asarray(v), np.asarray(m))
+                    for k, (v, m) in entry.outs.items()
                 }
-                moved += st.retire(lane_outs)
-        else:
-            (st,) = entry.riders
-            moved += st.retire(entry.outs)
-        dt = time.perf_counter_ns() - t0
-        if self.telemetry is not None:
-            self.telemetry.device_retired(moved, dt + entry.t_launch_ns)
-        if self.recorder is not None:
-            # args.time_ns carries the telemetry value (retire + its share
-            # of the launch call) so replay matches device_time_ns exactly;
-            # the span itself shows the host-side retire work
-            self.recorder.complete(
-                self._track, "retire", "device", t0, dt,
-                {
-                    "tokens_out": moved,
-                    "lanes": len(entry.riders),
-                    "time_ns": dt + entry.t_launch_ns,
-                },
+                for lane, st in enumerate(entry.riders):
+                    lane_outs = {
+                        k: (v[lane], m[lane]) for k, (v, m) in outs_np.items()
+                    }
+                    moved += st.retire(lane_outs)
+            else:
+                (st,) = entry.riders
+                moved += st.retire(entry.outs)
+            # telemetry's device time is this retire plus the launch call's
+            # wall time; args.time_ns carries that same value so replay
+            # matches device_time_ns exactly, while the span itself shows
+            # the host-side retire work
+            time_ns = time.perf_counter_ns() - sp.t0_ns + entry.t_launch_ns
+            if self.telemetry is not None:
+                self.telemetry.device_retired(moved, time_ns)
+            sp.args.update(
+                tokens_out=moved, lanes=len(entry.riders), time_ns=time_ns
             )
         return moved
 
@@ -331,9 +343,9 @@ class DeviceBatcher:
     def pending(self) -> bool:
         return bool(self.inflight)
 
-    def drain(self) -> int:
+    def drain(self, round_no: int = 0) -> int:
         """Force-retire everything in flight (poll only forces the oldest)."""
         moved = 0
         while self.inflight:
-            moved += self.poll(block=True)
+            moved += self.poll(block=True, round_no=round_no)
         return moved

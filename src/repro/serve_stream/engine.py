@@ -37,7 +37,7 @@ import traceback
 from typing import Dict, List, Optional, Tuple, Union
 
 from repro.observability.metrics import MetricsRegistry
-from repro.observability.recorder import TraceRecorder
+from repro.observability.recorder import TraceRecorder, span
 from repro.observability.trace_profile import authored_channel_key
 from repro.runtime import chaos as chaos_mod
 from repro.runtime.scheduler import AdaptiveBackoff
@@ -271,7 +271,7 @@ class StreamServer:
             # engine not running: this thread owns all state — the
             # boundary is trivially drained
             for b in self._batchers.values():
-                b.drain()
+                b.drain(self._round)
             return recovery.write_checkpoint(
                 self, ckpt_dir, step=step, keep=keep
             )
@@ -525,31 +525,43 @@ class StreamServer:
                 swapping = self._pending_xcf is not None
             moved = 0
             self._round += 1
-            if self._round % 128 == 1:
+            r = self._round
+            rec = self.recorder
+            if r % 128 == 1:
                 # refresh the scheduler's view of the TTFO tail — the
                 # histogram walk is too costly to run every round
                 self._ttfo_p95 = self._h_ttfo.percentile(95)
+
+            # Each phase below runs under a flat span (``repro.engine.
+            # <phase>`` in the profiler's trace, track ``engine`` in the
+            # recorder) carrying the round number.  The batcher's retire
+            # and launch spans sit between them, the launch splitting the
+            # ``order`` phase in two: ordering before it, charging after.
 
             # 1) admission pump (paused while a swap is draining).  Every
             # per-session step is blast-radius isolated: ONE stream's
             # failure (its actor raising, its bad input) fails that
             # session — with the captured traceback delivered to its
             # client — and the engine keeps serving everyone else.
-            if not swapping:
-                for s in active:
-                    moved += self._guarded(
-                        s, s.pipeline.pump, "admission pump",
-                        self.telemetry,
-                    )
-            if moved:
-                with self._wake:  # free space -> unblock submitters
-                    self._wake.notify_all()
+            with span(rec, "engine", "engine", "pump", round=r) as sp:
+                if not swapping:
+                    for s in active:
+                        moved += self._guarded(
+                            s, s.pipeline.pump, "admission pump",
+                            self.telemetry,
+                        )
+                if moved:
+                    sp.args["tokens"] = moved  # replays tokens_pumped
+                    with self._wake:  # free space -> unblock submitters
+                        self._wake.notify_all()
 
             # 2) host actors
-            for s in active:
-                moved += self._guarded(
-                    s, s.pipeline.host_round, "host round", self.telemetry
-                )
+            with span(rec, "engine", "engine", "host", round=r):
+                for s in active:
+                    moved += self._guarded(
+                        s, s.pipeline.host_round, "host round",
+                        self.telemetry,
+                    )
 
             # 3) device lanes: per partition, retire what finished, then
             # launch one continuous round from whatever is ready — riding an
@@ -563,33 +575,37 @@ class StreamServer:
             now_ns = time.perf_counter_ns()
             for pid, batcher in self._batchers.items():
                 try:
-                    moved += batcher.poll()
+                    moved += batcher.poll(round_no=r)
                 except Exception as e:  # retire failed: rounds are lost
                     self._poll_failed(pid, batcher, e)
                     degrade = (pid, e)
                     break
-                cands = []
-                for s in active:
-                    if s.finished.is_set():
-                        continue
-                    stage = s.pipeline.stages.get(pid)
-                    if stage is not None and stage.ready_tokens() > 0:
-                        cands.append((s, stage))
-                if cands and batcher.can_launch():
-                    ordered = self._sched.order(
-                        cands, now_ns=now_ns, ttfo_p95_s=self._ttfo_p95
-                    )
-                    before = [
-                        (s, st, st.tokens_staged) for s, st in ordered
-                    ]
+                before = None
+                with span(rec, "engine", "engine", "order", round=r):
+                    cands = []
+                    for s in active:
+                        if s.finished.is_set():
+                            continue
+                        stage = s.pipeline.stages.get(pid)
+                        if stage is not None and stage.ready_tokens() > 0:
+                            cands.append((s, stage))
+                    if cands and batcher.can_launch():
+                        ordered = self._sched.order(
+                            cands, now_ns=now_ns, ttfo_p95_s=self._ttfo_p95
+                        )
+                        before = [
+                            (s, st, st.tokens_staged) for s, st in ordered
+                        ]
+                if before is not None:
                     lanes, fatal = self._launch_with_retry(
-                        pid, batcher, [st for _s, st in ordered]
+                        pid, batcher, [st for _s, st, _t in before], r
                     )
                     moved += lanes
-                    for s, st, t0 in before:
-                        d = st.tokens_staged - t0
-                        if d:
-                            self._sched.charge(s.sid, d, self._round)
+                    with span(rec, "engine", "engine", "order", round=r):
+                        for s, st, t0 in before:
+                            d = st.tokens_staged - t0
+                            if d:
+                                self._sched.charge(s.sid, d, r)
                     if fatal is not None:
                         degrade = (pid, fatal)
                         break
@@ -602,92 +618,97 @@ class StreamServer:
                 continue
 
             # 4) egress
-            for s in active:
-                if s.finished.is_set():
-                    continue
-                n = self._guarded(
-                    s, s.pipeline.drain_egress, "egress drain"
-                )
-                if n:
-                    self.telemetry.count("tokens_delivered", n)
-                    self._observe_delivery(s, n)
-                moved += n
+            with span(rec, "engine", "engine", "egress", round=r):
+                for s in active:
+                    if s.finished.is_set():
+                        continue
+                    n = self._guarded(
+                        s, s.pipeline.drain_egress, "egress drain"
+                    )
+                    if n:
+                        self.telemetry.count("tokens_delivered", n)
+                        self._observe_delivery(s, n)
+                    moved += n
 
-            # 5) session completion
-            for s in active:
-                if s.finished.is_set():
-                    continue
-                if (
-                    s.closed
-                    and all(s.queued_tokens(n) == 0 for n in s.queues)
-                    and s.pipeline.quiescent()
-                ):
-                    self._record_links(s.pipeline)
-                    s.finished.set()
-                    self._session_closed(s)
-                    with self._wake:
-                        self._wake.notify_all()
-
-            # 5b) checkpoint: explicit requests and the periodic schedule
-            # both write at this point — after completion, before swaps —
-            # with the device lanes force-drained first (a real block
-            # boundary; see serve_stream.recovery)
-            with self._lock:
-                req, self._ckpt_request = self._ckpt_request, None
-            if req is None and self._ckpt_dir is not None \
-                    and self._ckpt_every is not None:
-                now = time.perf_counter()
-                if now - self._ckpt_last >= self._ckpt_every:
-                    self._ckpt_last = now
-                    with self._lock:
-                        self._ckpt_step += 1
-                        step = self._ckpt_step
-                    req = {
-                        "dir": self._ckpt_dir, "step": step, "keep": 3,
-                        "event": None, "path": None, "error": None,
-                    }
-            if req is not None:
-                self._write_checkpoint(req)
-
-            # 6) swap / repartition bookkeeping (the repartitioner is
-            # ignored while degraded: the quarantined device must not be
-            # re-proposed by a MILP that cannot see it is dead)
-            if swapping and not pending_device:
-                if all(
-                    s.pipeline.quiescent()
-                    for s in active if not s.finished.is_set()
-                ):
-                    self._do_swap()
-                    continue
-            if (
-                self.repartitioner is not None
-                and not swapping
-                and not self._quarantined
-            ):
-                # flush live sessions' link deltas into the window first, so
-                # the MILP sees channel traffic from still-open streams too
-                if self._round % 32 == 0:
-                    for s in active:
+            # 5) session completion, 5b) checkpoint, 6) swap/repartition
+            with span(rec, "engine", "engine", "complete", round=r):
+                for s in active:
+                    if s.finished.is_set():
+                        continue
+                    if (
+                        s.closed
+                        and all(s.queued_tokens(n) == 0 for n in s.queues)
+                        and s.pipeline.quiescent()
+                    ):
                         self._record_links(s.pipeline)
-                xcf = self.repartitioner.maybe()
-                if xcf is not None:
-                    with self._lock:
-                        self._pending_xcf = xcf
+                        s.finished.set()
+                        self._session_closed(s)
+                        with self._wake:
+                            self._wake.notify_all()
+
+                # 5b) checkpoint: explicit requests and the periodic
+                # schedule both write at this point — after completion,
+                # before swaps — with the device lanes force-drained first
+                # (a real block boundary; see serve_stream.recovery)
+                with self._lock:
+                    req, self._ckpt_request = self._ckpt_request, None
+                if req is None and self._ckpt_dir is not None \
+                        and self._ckpt_every is not None:
+                    now = time.perf_counter()
+                    if now - self._ckpt_last >= self._ckpt_every:
+                        self._ckpt_last = now
+                        with self._lock:
+                            self._ckpt_step += 1
+                            step = self._ckpt_step
+                        req = {
+                            "dir": self._ckpt_dir, "step": step, "keep": 3,
+                            "event": None, "path": None, "error": None,
+                        }
+                if req is not None:
+                    self._write_checkpoint(req)
+
+                # 6) swap / repartition bookkeeping (the repartitioner is
+                # ignored while degraded: the quarantined device must not
+                # be re-proposed by a MILP that cannot see it is dead)
+                if swapping and not pending_device:
+                    if all(
+                        s.pipeline.quiescent()
+                        for s in active if not s.finished.is_set()
+                    ):
+                        self._do_swap()
+                        continue
+                if (
+                    self.repartitioner is not None
+                    and not swapping
+                    and not self._quarantined
+                ):
+                    # flush live sessions' link deltas into the window
+                    # first, so the MILP sees channel traffic from
+                    # still-open streams too
+                    if r % 32 == 0:
+                        for s in active:
+                            self._record_links(s.pipeline)
+                    xcf = self.repartitioner.maybe()
+                    if xcf is not None:
+                        with self._lock:
+                            self._pending_xcf = xcf
 
             # 7) park when idle — adaptive: a short ramp while a device step
             # is in flight (poll it soon), a CV wait when truly idle (only a
             # submit/close/stop can create work, and each notifies)
             if moved == 0:
                 if pending_device:
-                    dev_backoff.pause()
+                    with span(rec, "engine", "engine", "park", round=r):
+                        dev_backoff.pause()
                 elif self._stall_check(active, swapping):
                     continue
                 else:
-                    with self._wake:
-                        if not self._stop:
-                            self._wake.wait(
-                                max(backoff.next_timeout(), 1e-4)
-                            )
+                    with span(rec, "engine", "engine", "park", round=r):
+                        with self._wake:
+                            if not self._stop:
+                                self._wake.wait(
+                                    max(backoff.next_timeout(), 1e-4)
+                                )
             else:
                 backoff.reset()
                 dev_backoff.reset()
@@ -699,7 +720,7 @@ class StreamServer:
             return
         # shutdown: flush anything still in flight so state stays consistent
         for batcher in self._batchers.values():
-            batcher.drain()
+            batcher.drain(self._round)
         # ...and flush egress: the drain above retires tokens into FIFOs
         # *behind* the egress drain of the loop's last round, possibly with
         # host actors still between them — without this, tokens retired
@@ -769,7 +790,7 @@ class StreamServer:
             self._wake.notify_all()
 
     def _launch_with_retry(
-        self, pid: str, batcher: DeviceBatcher, stages: List
+        self, pid: str, batcher: DeviceBatcher, stages: List, round_no: int
     ) -> Tuple[int, Optional[BaseException]]:
         """Bounded exponential-backoff retry around one device launch.
 
@@ -781,7 +802,7 @@ class StreamServer:
         delay = self.retry_base_s
         for attempt in range(self.launch_retries + 1):
             try:
-                lanes = batcher.launch(stages)
+                lanes = batcher.launch(stages, round_no)
             except DeviceCompileError:
                 raise  # the program cannot run on its device: not a fault
             except Exception as e:  # noqa: PERF203 — the retry loop IS the point
@@ -863,7 +884,7 @@ class StreamServer:
 
         try:
             for b in self._batchers.values():
-                b.drain()
+                b.drain(self._round)
             req["path"] = recovery.write_checkpoint(
                 self, req["dir"], step=req["step"], keep=req["keep"]
             )
@@ -1029,11 +1050,11 @@ class StreamServer:
                         # first (no tokens lost); fail the riders loudly
                         # only when retirement itself is broken
                         try:
-                            b.drain()
+                            b.drain(self._round)
                         except Exception as e:  # noqa: BLE001
                             self._poll_failed(pid, b, e)
                     elif pid not in self._quarantined:
-                        b.drain()
+                        b.drain(self._round)
             self._program = old.repartition(xcf=xcf)
             self._batchers = self._make_batchers()
             for s in self._sessions:

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.core.actor_machine import ActorMachine, BasicController, PortEnv
 from repro.ir.ir import IRModule
+from repro.observability.recorder import span
 from repro.observability.trace_profile import authored_channel_key
 from repro.runtime.fifo import ReaderEndpoint, RingFifo, WriterEndpoint
 from repro.runtime.plink import _np_dtype
@@ -552,7 +553,7 @@ class SessionPipeline:
             q.publish_reader()  # free the space for blocked submitters
             moved += n
             if telemetry is not None:
-                telemetry.queue_depth(q.count())
+                telemetry.pumped(n, q.count())
         return moved
 
     def host_round(self, telemetry=None) -> int:
@@ -569,19 +570,17 @@ class SessionPipeline:
                 # chaos site: one occurrence per actor invoke per round —
                 # ``actor:<name>@s<sid>`` targets one session's actors
                 ch.poke(f"actor:{name}@s{self.session.sid}")
-            t0 = time.perf_counter_ns()
-            e = inst.invoke(self.max_execs_per_invoke)
-            if e:
-                dt = time.perf_counter_ns() - t0
-                key = getattr(inst, "telemetry_key", name)
-                if telemetry is not None:
-                    telemetry.actor_fired(key, e, dt)
-                if rec is not None:
-                    # same key/fires/duration as the telemetry record, so a
-                    # trace replay reproduces the live actor-time totals
-                    rec.complete(
-                        self._track, key, "actor", t0, dt, {"fires": e}
-                    )
+            key = getattr(inst, "telemetry_key", name)
+            with span(rec, self._track, "actor", key) as sp:
+                e = inst.invoke(self.max_execs_per_invoke)
+                if e:
+                    sp.args["fires"] = e
+                else:
+                    sp.discard()  # only productive invokes are recorded
+            if e and telemetry is not None:
+                # the span's own duration: a trace replay reproduces the
+                # live actor-time totals exactly
+                telemetry.actor_fired(key, e, sp.dur_ns)
             execs += e
         return execs
 

@@ -46,6 +46,7 @@ class TelemetrySnapshot:
     tokens_delivered: int
     queue_peak: int                              # deepest admission queue seen
     swaps: int                                   # XCF hot-swaps in the window
+    tokens_pumped: int = 0                       # admission queues -> ingress FIFOs
 
     @property
     def mean_batch(self) -> float:
@@ -91,7 +92,7 @@ class ServerTelemetry:
             sessions_opened=0, sessions_closed=0,
             chunks_submitted=0, chunks_split=0,
             tokens_submitted=0, tokens_delivered=0,
-            queue_peak=0, swaps=0,
+            queue_peak=0, swaps=0, tokens_pumped=0,
         )
 
     # -- recording (engine thread + admission-side client threads) -----------
@@ -153,9 +154,13 @@ class ServerTelemetry:
                 d["tokens_submitted"] += tokens
                 d["chunks_split"] += split
 
-    def queue_depth(self, depth: int) -> None:
+    def pumped(self, tokens: int, depth: int) -> None:
+        """One admission pump of ``tokens`` leaving a queue ``depth`` deep:
+        both under one lock acquisition, since the engine thread pumps
+        every session every round while client threads submit."""
         with self._lock:
             for d in (self._win, self.totals):
+                d["tokens_pumped"] += tokens
                 if depth > d["queue_peak"]:
                     d["queue_peak"] = depth
 
@@ -179,6 +184,7 @@ class ServerTelemetry:
                     "sessions_opened", "sessions_closed",
                     "chunks_submitted", "chunks_split", "tokens_submitted",
                     "tokens_delivered", "queue_peak", "swaps",
+                    "tokens_pumped",
                 )
             },
         )
