@@ -118,7 +118,7 @@ def snapshot_from_trace(
     device_time_ns = 0
     tok_in = tok_out = 0
     opened = closed = chunks = split = submitted = delivered = swaps = 0
-    pumped = 0
+    pumped = delivered_blocks = 0
     queue_peak = 0
     t_lo: Optional[float] = None
     t_hi = 0.0
@@ -187,6 +187,7 @@ def snapshot_from_trace(
                 queue_peak = max(queue_peak, int(args.get("queued", 0)))
             elif ev["name"] == "deliver":
                 delivered += int(args.get("tokens", 0))
+                delivered_blocks += int(args.get("blocks", 0))
         elif cat == "engine":
             if ev["name"] == "hot_swap":
                 swaps += 1
@@ -216,4 +217,5 @@ def snapshot_from_trace(
         queue_peak=queue_peak,
         swaps=swaps,
         tokens_pumped=pumped,
+        tokens_delivered_blocks=delivered_blocks,
     )

@@ -158,7 +158,8 @@ class RingFifo:
 
 
 class ArrayFifo:
-    """Numpy-block FIFO for device→device PLink lanes.
+    """Numpy-block FIFO for device→device PLink lanes and, in serve mode,
+    for a device partition's channel into a session's result buffer.
 
     A channel between two accelerator partitions never carries host tokens:
     the producing PLink retires whole masked blocks and the consuming PLink
@@ -253,13 +254,20 @@ class ArrayFifo:
 
     def commit(self, n: int) -> None:
         """Consume ``n`` tokens previously obtained via ``peek_view``."""
+        self.read_blocks(n)
+
+    def read_blocks(self, n: int) -> List[Any]:
+        """Consume ``n`` tokens as the blocks (or block slices) that hold
+        them, oldest first — no concatenate, no per-token objects."""
         assert self.count() >= n, (
-            f"{self.name}: commit({n}) with {self.count()}"
+            f"{self.name}: read_blocks({n}) with {self.count()}"
         )
+        parts = []
         got = 0
         while got < n:
             blk = self._blocks[0]
             take = min(len(blk) - self._head, n - got)
+            parts.append(blk[self._head:self._head + take])
             got += take
             if self._head + take == len(blk):
                 self._blocks.pop(0)
@@ -267,6 +275,7 @@ class ArrayFifo:
             else:
                 self._head += take
         self._r += n
+        return parts
 
     # -- writer API ----------------------------------------------------------
     def space(self) -> int:

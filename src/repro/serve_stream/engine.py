@@ -619,16 +619,20 @@ class StreamServer:
 
             # 4) egress
             with span(rec, "engine", "engine", "egress", round=r):
+                delivered = blocks = 0
                 for s in active:
                     if s.finished.is_set():
                         continue
-                    n = self._guarded(
+                    n, nb = self._guarded(
                         s, s.pipeline.drain_egress, "egress drain"
-                    )
+                    ) or (0, 0)
                     if n:
-                        self.telemetry.count("tokens_delivered", n)
-                        self._observe_delivery(s, n)
-                    moved += n
+                        self._observe_delivery(s, n, nb)
+                        delivered += n
+                        blocks += nb
+                if delivered:
+                    self.telemetry.delivered(delivered, blocks)
+                moved += delivered
 
             # 5) session completion, 5b) checkpoint, 6) swap/repartition
             with span(rec, "engine", "engine", "complete", round=r):
@@ -738,12 +742,12 @@ class StreamServer:
                     self.telemetry,
                 ):
                     progressed = True
-                n = self._guarded(
+                n, nb = self._guarded(
                     s, s.pipeline.drain_egress, "shutdown flush"
-                )
+                ) or (0, 0)
                 if n:
-                    self.telemetry.count("tokens_delivered", n)
-                    self._observe_delivery(s, n)
+                    self.telemetry.delivered(n, nb)
+                    self._observe_delivery(s, n, nb)
                     progressed = True
 
     # -- fault paths: isolate, retry, degrade ---------------------------------
@@ -973,7 +977,7 @@ class StreamServer:
                 {"error": bool(s.error)},
             )
 
-    def _observe_delivery(self, s: StreamSession, n: int) -> None:
+    def _observe_delivery(self, s: StreamSession, n: int, blocks: int) -> None:
         """Per-session SLO accounting at the moment tokens reach the client
         buffer: TTFO on the first delivery, inter-block gap on every later
         one, plus the trace's ``deliver`` instant."""
@@ -988,7 +992,8 @@ class StreamServer:
         s.last_delivery_ns = now
         if self.recorder is not None:
             self.recorder.instant(
-                f"session:{s.sid}", "deliver", "session", {"tokens": n}
+                f"session:{s.sid}", "deliver", "session",
+                {"tokens": n, "blocks": blocks},
             )
 
     def _record_links(self, pipeline: SessionPipeline) -> None:

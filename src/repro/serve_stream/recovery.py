@@ -15,6 +15,7 @@ block boundary and rebuilds it:
                             at the boundary because the engine force-drains
                             every batcher before snapshotting
   delivered results         per-egress output buffers as of the checkpoint
+                            (one numeric array for a device-fed port)
 
 Storage reuses ``repro.checkpoint``'s atomic manifest+npy layout (temp dir,
 atomic rename, ``latest`` written last): a crash mid-checkpoint leaves the
@@ -22,7 +23,8 @@ previous complete step as the restore point.  Host token streams and actor
 states are stored as pickled object arrays — exact Python/NumPy scalar
 types round-trip, which bit-identity requires (a ``np.float32`` token that
 came off the device must not come back as a Python float; NumPy promotion
-rules differ).  Device state stays numeric npy.
+rules differ).  Device state, and a device-fed port's delivered tokens,
+stay numeric npy.
 
 Recovery contract (docs/reliability.md):
 
@@ -108,6 +110,18 @@ def _obj_arr(values: List) -> np.ndarray:
     return arr
 
 
+def _result_arr(buf) -> np.ndarray:
+    """One port's delivered tokens: one numeric array when every chunk is a
+    numeric block of one dtype (a device-fed sink), else the exact-type
+    object array."""
+    chunks = buf.chunks
+    if all(isinstance(c, np.ndarray) for c in chunks):
+        dtypes = {c.dtype for c in chunks}
+        if len(dtypes) == 1 and dtypes.pop().kind in "biuf":
+            return np.concatenate(chunks)
+    return _obj_arr(list(buf))
+
+
 def _host_view(state: Dict) -> Dict:
     """Actor-state dict with jax arrays materialized to numpy (picklable,
     and independent of any device buffer the engine may later donate)."""
@@ -140,7 +154,7 @@ def snapshot_server(server) -> Tuple[Dict[str, np.ndarray], Dict]:
             "in_pipeline": 0,
         }
         for port, vals in s.results.items():
-            tree[f"s{s.sid}/result/{port}"] = _obj_arr(list(vals))
+            tree[f"s{s.sid}/result/{port}"] = _result_arr(vals)
         if not s.finished.is_set() and p is not None:
             # admission residue: peek, never consume — a checkpoint must
             # not perturb the stream it snapshots
@@ -282,7 +296,9 @@ def recover(
             for port in s.results:
                 arr = flat.get(f"s{sid}/result/{port}")
                 if arr is not None and arr.size:
-                    s.results[port].extend(arr.tolist())
+                    s.results[port].extend(
+                        arr.tolist() if arr.dtype == object else arr
+                    )
             if m.get("finished"):
                 s.pipeline = server._build_pipeline(s)
                 s.finished.set()

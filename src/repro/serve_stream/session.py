@@ -12,7 +12,10 @@ The pipeline is the serve-mode reading of the lowered module (Fig. 6):
     mode the client IS the source, so each source's output channel becomes
     an ingress FIFO pumped from the session's admission queue;
   * **sink actors** (no output ports) are *not* instantiated — their input
-    channels become egress FIFOs drained into ``session.output(port)``;
+    channels become egress FIFOs drained into ``session.output(port)``, a
+    ``DeliveredTokens`` buffer; a sink fed by a device partition gets an
+    ``ArrayFifo``, so its tokens stay numpy blocks from retire to the
+    client;
   * **device actors** are replaced by one ``DeviceStage`` per device
     partition: the PLink lane's stage/retire halves with the launch in the
     middle handed to that partition's shared ``DeviceBatcher``, so B
@@ -32,6 +35,7 @@ back), so a session's outputs are bit-identical to a sequential
 
 from __future__ import annotations
 
+import bisect
 import math
 import threading
 import time
@@ -43,7 +47,12 @@ from repro.core.actor_machine import ActorMachine, BasicController, PortEnv
 from repro.ir.ir import IRModule
 from repro.observability.recorder import span
 from repro.observability.trace_profile import authored_channel_key
-from repro.runtime.fifo import ReaderEndpoint, RingFifo, WriterEndpoint
+from repro.runtime.fifo import (
+    ArrayFifo,
+    ReaderEndpoint,
+    RingFifo,
+    WriterEndpoint,
+)
 from repro.runtime.plink import _np_dtype
 
 
@@ -58,6 +67,110 @@ class DeviceCompileError(ServeError):
 
 class AdmissionFull(ServeError):
     """Non-blocking submit against a full admission queue."""
+
+
+class DeliveredTokens:
+    """Tokens delivered on one egress port, kept as the chunks they arrived
+    in: numpy blocks from a device-fed sink (no per-token Python object
+    between the device and this buffer), lists from a host-fed one.
+
+    Reads like the list it replaces: ``len`` in O(1), an integer index
+    returns one token, iteration yields every token, ``==`` compares
+    element-wise with any sequence and returns a bool.  A slice and
+    ``np.asarray`` return one ndarray (the stored dtype for array chunks)
+    built from the chunks without boxing.  One writer (the engine thread)
+    appends; a reader on another thread sees a consistent prefix.
+    """
+
+    __hash__ = None  # mutable, compared by value
+
+    def __init__(self) -> None:
+        self._chunks: List = []  # np.ndarray blocks and lists of tokens
+        self._ends: List[int] = []  # cumulative token count per chunk
+        self._n = 0
+
+    @property
+    def chunks(self) -> List:
+        """The stored chunks, oldest first."""
+        return self._chunks[:len(self._ends)]
+
+    def extend(self, vals) -> None:
+        if isinstance(vals, np.ndarray):
+            vals = vals.reshape(-1)
+            if not vals.size:
+                return
+            self._chunks.append(vals)
+            self._ends.append(self._n + vals.size)
+        else:
+            vals = list(vals)
+            if not vals:
+                return
+            if self._chunks and isinstance(self._chunks[-1], list):
+                self._chunks[-1].extend(vals)  # host tokens: one list
+                self._ends[-1] += len(vals)
+            else:
+                self._chunks.append(vals)
+                self._ends.append(self._n + len(vals))
+        self._n = self._ends[-1]
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __iter__(self):
+        for chunk in self.chunks:
+            yield from chunk
+
+    def __getitem__(self, key):
+        n = self._n
+        if isinstance(key, slice):
+            lo, hi, step = key.indices(n)
+            if step != 1:
+                return np.asarray(self)[key]
+            return self._span(lo, max(lo, hi))
+        i = key + n if key < 0 else key
+        if not 0 <= i < n:
+            raise IndexError(f"delivered token {key} of {n}")
+        k = bisect.bisect_right(self._ends, i)
+        return self._chunks[k][i - (self._ends[k - 1] if k else 0)]
+
+    def _span(self, lo: int, hi: int) -> np.ndarray:
+        """Tokens ``[lo, hi)`` as one ndarray: one copy, no boxing."""
+        ends = self._ends[:]
+        parts = []
+        k = bisect.bisect_right(ends, lo)
+        while lo < hi:
+            start = ends[k - 1] if k else 0
+            take = min(ends[k], hi) - lo
+            parts.append(self._chunks[k][lo - start:lo - start + take])
+            lo += take
+            k += 1
+        if not parts:
+            return np.asarray(self._chunks[0][:0] if self._chunks else [])
+        return np.concatenate(parts)
+
+    def __array__(self, dtype=None, copy=None):
+        arr = self._span(0, self._n)
+        return arr if dtype is None else arr.astype(dtype, copy=False)
+
+    def __eq__(self, other) -> bool:
+        try:
+            if len(other) != self._n:
+                return False
+        except TypeError:
+            return NotImplemented
+        try:
+            mine, theirs = np.asarray(self), np.asarray(other)
+            if object not in (mine.dtype, theirs.dtype):
+                return bool(np.array_equal(mine, theirs))
+        except ValueError:  # ragged tokens
+            pass
+        return list(self) == list(other)  # Python objects, one by one
+
+    def __repr__(self) -> str:
+        return (
+            f"DeliveredTokens({self._n} tokens in "
+            f"{len(self._ends)} chunks)"
+        )
 
 
 class StreamSession:
@@ -87,7 +200,9 @@ class StreamSession:
             )
             for name in ingress
         }
-        self.results: Dict[str, List] = {name: [] for name in egress}
+        self.results: Dict[str, DeliveredTokens] = {
+            name: DeliveredTokens() for name in egress
+        }
         self.closed = False
         self.finished = threading.Event()
         self.pipeline: Optional[SessionPipeline] = None  # set by the server
@@ -205,7 +320,7 @@ class StreamSession:
         q.snapshot_reader()
         return q.count()
 
-    def output(self, port: Optional[str] = None) -> List:
+    def output(self, port: Optional[str] = None) -> DeliveredTokens:
         """Tokens delivered on one egress port (the only one by default)."""
         if self.error is not None:
             raise ServeError(self.error)
@@ -347,8 +462,8 @@ class DeviceStage:
             vals = np.asarray(vals)
             keep = vals[np.asarray(mask)]
             if keep.size:
-                # a RingFifo boxes host tokens; a device->device ArrayFifo
-                # queues the array itself
+                # a RingFifo boxes host tokens; an ArrayFifo (to another
+                # partition or a sink) queues the array itself
                 self.out_eps[key].write(keep)
                 moved += int(keep.size)
         self.inflight -= 1
@@ -385,8 +500,6 @@ class SessionPipeline:
         recorder=None,
         chaos=None,
     ):
-        from repro.runtime.fifo import ArrayFifo
-
         self.module = module
         self.session = session
         self.max_execs_per_invoke = max_execs_per_invoke
@@ -432,9 +545,10 @@ class SessionPipeline:
             s_pid, d_pid = hw_of.get(ch.src), hw_of.get(ch.dst)
             if s_pid is not None and s_pid == d_pid:
                 continue  # compiled inside one device program
-            if s_pid is not None and d_pid is not None:
-                # device -> device across partitions: numpy blocks, never
-                # per-token Python objects
+            if s_pid is not None and (d_pid is not None or ch.dst in sinks):
+                # device -> device across partitions, or device -> the
+                # session's result buffer: numpy blocks, never per-token
+                # Python objects
                 f = ArrayFifo(
                     ch.resolved_depth or default_depth,
                     name=f"s{session.sid}:{ch}",
@@ -584,15 +698,24 @@ class SessionPipeline:
             execs += e
         return execs
 
-    def drain_egress(self) -> int:
-        """Egress FIFOs -> session result buffers."""
-        moved = 0
+    def drain_egress(self) -> Tuple[int, int]:
+        """Egress FIFOs -> session result buffers.  Returns the tokens
+        delivered and how many of them arrived as array blocks (from a
+        device-fed sink's ``ArrayFifo``, appended without boxing)."""
+        moved = blocks = 0
         for sink, fifo in self.egress:
             n = fifo.count()
-            if n:
-                self.session.results[sink].extend(fifo.read(n))
-                moved += n
-        return moved
+            if not n:
+                continue
+            buf = self.session.results[sink]
+            if isinstance(fifo, ArrayFifo):
+                for blk in fifo.read_blocks(n):
+                    buf.extend(blk)
+                blocks += n
+            else:
+                buf.extend(fifo.read(n))
+            moved += n
+        return moved, blocks
 
     @property
     def stage(self) -> Optional[DeviceStage]:

@@ -47,6 +47,7 @@ class TelemetrySnapshot:
     queue_peak: int                              # deepest admission queue seen
     swaps: int                                   # XCF hot-swaps in the window
     tokens_pumped: int = 0                       # admission queues -> ingress FIFOs
+    tokens_delivered_blocks: int = 0             # of tokens_delivered, as array blocks
 
     @property
     def mean_batch(self) -> float:
@@ -93,6 +94,7 @@ class ServerTelemetry:
             chunks_submitted=0, chunks_split=0,
             tokens_submitted=0, tokens_delivered=0,
             queue_peak=0, swaps=0, tokens_pumped=0,
+            tokens_delivered_blocks=0,
         )
 
     # -- recording (engine thread + admission-side client threads) -----------
@@ -164,6 +166,15 @@ class ServerTelemetry:
                 if depth > d["queue_peak"]:
                     d["queue_peak"] = depth
 
+    def delivered(self, tokens: int, blocks: int) -> None:
+        """One engine round's egress: ``tokens`` reached result buffers,
+        ``blocks`` of them as array blocks — one lock acquisition per
+        round, not one per session."""
+        with self._lock:
+            for d in (self._win, self.totals):
+                d["tokens_delivered"] += tokens
+                d["tokens_delivered_blocks"] += blocks
+
     def swapped(self, detail: Dict) -> None:
         self.count("swaps")
         self.swap_log.append(dict(detail, at=time.perf_counter()))
@@ -184,7 +195,7 @@ class ServerTelemetry:
                     "sessions_opened", "sessions_closed",
                     "chunks_submitted", "chunks_split", "tokens_submitted",
                     "tokens_delivered", "queue_peak", "swaps",
-                    "tokens_pumped",
+                    "tokens_pumped", "tokens_delivered_blocks",
                 )
             },
         )
