@@ -6,7 +6,9 @@ the compiled device partition, or a mix — which is the point of the paper.
 Actors that can run on the device carry a ``vector_fire``.
 
   * topfilter — the paper's Listing-1 network (guarded filter + priority)
-  * fir       — N-tap systolic FIR pipeline (paper: 34 actors / 1D convolution)
+  * fir       — N-tap systolic FIR pipeline (paper: 34 actors / 1D convolution);
+                ``FirTap`` is the tap with a delay register (a true
+                convolution), ``Mac`` the stateless one FIR32 authors
   * bitonic8  — 8-lane bitonic sorting network of compare-exchange actors
                 (paper: 28 actors / hardware sorting)
   * idct8     — 8-point IDCT actor network (paper: 7 actors)
@@ -119,6 +121,51 @@ class Mac:
         xv, xm = ins["XIN"]
         av, am = ins["AIN"]
         return state, {"XOUT": (xv, xm), "AOUT": (av + self.c * xv, am)}
+
+
+def masked_delay(x, mask, z):
+    """One-token delay of the valid tokens of a ``(N,)`` wire: each valid
+    position reads the previous *valid* token, the first one reads the
+    register ``z``.  Returns ``(delayed, new z)``, the new register being the
+    last valid token (``z`` itself when none is valid), so padding never
+    enters the delay line whatever the mask looks like."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    z = jnp.asarray(z, x.dtype)
+    idx = jnp.where(mask, jnp.arange(x.shape[0]), -1)
+    last = lax.cummax(idx)
+    prev = jnp.concatenate([jnp.full((1,), -1, last.dtype), last[:-1]])
+    delayed = jnp.where(prev >= 0, x[jnp.maximum(prev, 0)], z)
+    new_z = jnp.where(last[-1] >= 0, x[jnp.maximum(last[-1], 0)], z)
+    return delayed, new_z
+
+
+@actor(inputs={"XIN": "float32", "AIN": "float32"},
+       outputs={"XOUT": "float32", "AOUT": "float32"}, state={"z": 0.0})
+class FirTap:
+    """One tap of a systolic FIR with its delay register:
+    ``XOUT = z; z := x; AOUT = a + c * x``.  A chain of taps i = 0..n-1 gives
+    ``acc[n] = sum_i c_i x[n - i]`` and forwards x delayed by n samples."""
+
+    def __init__(self, c: float):
+        self.c = c
+        self.stream_op = ("fir_tap", c)
+
+    @action(name="t", consumes={"XIN": 1, "AIN": 1},
+            produces={"XOUT": 1, "AOUT": 1})
+    def t(self, st, t):
+        x = t["XIN"][0]
+        a = t["AIN"][0]
+        return {**st, "z": x}, {"XOUT": [st["z"]], "AOUT": [a + self.c * x]}
+
+    def vector_fire(self, state, ins):
+        xv, xm = ins["XIN"]
+        av, am = ins["AIN"]
+        delayed, z = masked_delay(xv, xm, state["z"])
+        return {**state, "z": z}, {
+            "XOUT": (delayed, xm), "AOUT": (av + self.c * xv, am),
+        }
 
 
 def fir(taps: int = 32, n: int = 4096) -> Tuple[Network, List]:

@@ -19,6 +19,18 @@ Two codegen strategies, picked per region:
 Masks never change inside an SDF region (rates are static, guards absent),
 so each fused output's validity mask is *selected* from the fused inputs at
 build time — the runtime moves only values.
+
+Stateful members fuse too when their spec names the state op that carries
+their state (``("fir_tap", c)``: a delay register).  The program then holds
+the members' registers, one ``delay`` op each; the fused actor's state is
+one float32 vector of them (``{"regs": (n_state,)}``, one device buffer
+per session lane), and the actor's ``state_slots`` name each register's
+``(member, state key)``, so hot swap and checkpoints map the registers
+back to their taps by name.  A delay's new
+register is the last valid token under its input's mask, which the
+stagers fill as a prefix of each launch; so such a region is built only
+where every delay's mask comes from a staged boundary input (one fed from
+outside the partition, not by a dynamic-rate actor beside it).
 """
 
 from __future__ import annotations
@@ -26,6 +38,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.actor import Action, Actor, Port
@@ -69,13 +82,20 @@ def _region_io(module, members: Sequence[str]):
 # ---------------------------------------------------------------------------
 
 
-def _translate_spec(spec, in_reg, new_reg, emit):
+# spec kinds whose member state a stream op carries: the state keys each
+# kind's ops read and write, in slot order
+STATE_KEYS = {"fir_tap": ("z",)}
+
+
+def _translate_spec(spec, in_reg, new_reg, emit, new_slot):
     """Lower one actor's ``stream_op`` spec to ops.
 
     Returns ``{out_port: (value_reg, mask_reg)}`` or None when the spec kind
     is unknown (the whole region then falls back to composed codegen).
     ``in_reg(port) -> (reg, mask_reg)``; masks are propagated exactly the way
-    the member's ``vector_fire`` propagates them.
+    the member's ``vector_fire`` propagates them.  ``new_slot(key, mask)
+    -> (slot, mask input)`` allocates a state register for the member's
+    state ``key``, governed by the fused input ``mask``.
     """
     kind = spec[0]
     if kind == "affine":
@@ -103,6 +123,14 @@ def _translate_spec(spec, in_reg, new_reg, emit):
         o = new_reg()
         emit(StreamOp("axpy", (x, a), o, (c,)))
         return {"XOUT": (x, xm), "AOUT": (o, am)}
+    if kind == "fir_tap":
+        c = float(spec[1])
+        x, xm = in_reg("XIN")
+        a, am = in_reg("AIN")
+        d, o = new_reg(), new_reg()
+        emit(StreamOp("delay", (x,), d, new_slot("z", xm)))
+        emit(StreamOp("axpy", (x, a), o, (c,)))
+        return {"XOUT": (d, xm), "AOUT": (o, am)}
     if kind == "fir_seed":
         x, m = in_reg("IN")
         z = new_reg()
@@ -135,11 +163,15 @@ def _try_stream_program(
     module, order: Sequence[str], b_ins, b_outs, internal, *, opt_level: int,
 ):
     """Build a StreamProgram for the region (members in topological
-    ``order``), or None if any member lacks a recognizable spec / has state /
-    isn't float32."""
+    ``order``), or None if any member lacks a recognizable spec / has state
+    no stream op carries / isn't float32.  Returns ``(program, output
+    masks, state slots)``, a slot being ``(member, state key)``."""
     for m in order:
         impl = module.actors[m].impl
-        if impl.stream_op is None or impl.initial_state:
+        if impl.stream_op is None:
+            return None
+        carried = STATE_KEYS.get(impl.stream_op[0], ())
+        if set(impl.initial_state) != set(carried):
             return None
         if any(p.dtype != "float32" for p in impl.inputs + impl.outputs):
             return None
@@ -156,8 +188,16 @@ def _try_stream_program(
         n_regs += 1
         return n_regs - 1
 
+    input_of = {_fused_port(ch.dst, ch.dst_port): i
+                for i, ch in enumerate(b_ins)}
+    slots: List[Tuple[str, str]] = []
+
     for m in order:
         spec = module.actors[m].impl.stream_op
+
+        def new_slot(key: str, mask: str, _m=m) -> Tuple[int, int]:
+            slots.append((_m, key))
+            return len(slots) - 1, input_of[mask]
 
         def in_reg(port: str, _m=m):
             try:
@@ -168,7 +208,8 @@ def _try_stream_program(
                     f"the region"
                 ) from None
 
-        produced = _translate_spec(spec, in_reg, new_reg, ops.append)
+        produced = _translate_spec(spec, in_reg, new_reg, ops.append,
+                                   new_slot)
         if produced is None:
             return None
         for ch in internal:
@@ -183,10 +224,44 @@ def _try_stream_program(
         reg, mask = wire[(ch.src, "__out__" + ch.src_port)]
         out_regs.append(reg)
         out_masks.append(mask)
-    prog = StreamProgram(len(b_ins), n_regs, tuple(ops), tuple(out_regs))
+    prog = StreamProgram(len(b_ins), n_regs, tuple(ops), tuple(out_regs),
+                         len(slots))
     if opt_level >= 2:
         prog = fold(prog)
-    return prog, out_masks
+    return prog, out_masks, slots
+
+
+def _staged_masks(module, order, b_ins, built) -> bool:
+    """Whether every delay's mask comes from a boundary input fed from
+    outside the region's partition — the stager then fills its valid
+    tokens as a prefix of the launch, which the delay op relies on.  A
+    stateless program needs nothing."""
+    program = built[0]
+    hw_of = module.hw_assignment()
+    home = hw_of.get(order[0])
+    for op in program.ops:
+        if op.kind == "delay":
+            src = b_ins[op.params[1]].src
+            if hw_of.get(src) == home:
+                return False
+    return True
+
+
+def _stateful_vf(program, out_masks, in_names, out_names):
+    """The fused actor's step for a program with state registers: the
+    registers ride in the state as one vector, and each input's
+    valid-token count comes from its mask."""
+
+    def vf(state, ins):
+        counts = jnp.stack([jnp.sum(ins[p][1].astype(jnp.int32))
+                            for p in in_names])
+        vals, regs = fused_stream([ins[p][0] for p in in_names], program,
+                                  state=state["regs"], counts=counts)
+        return {"regs": regs}, {
+            o: (v, ins[m][1]) for o, v, m in zip(out_names, vals, out_masks)
+        }
+
+    return vf
 
 
 # ---------------------------------------------------------------------------
@@ -242,9 +317,9 @@ def build_host_fused(
         )
     except GraphError:  # e.g. a feedback edge inside the group
         return None
-    if built is None:
+    if built is None or built[2]:  # host regions hold no state
         return None
-    program, _masks = built
+    program, _masks, _slots = built
     # The analyzer's region-restricted repetition vector is the single
     # source of truth for iteration shape: member m fires q[m] times per
     # region iteration, and every boundary channel moves rate*q[endpoint]
@@ -357,17 +432,25 @@ def build_fused(
     built = _try_stream_program(
         module, order, b_ins, b_outs, internal, opt_level=opt_level
     )
+    if built is not None and not _staged_masks(module, order, b_ins, built):
+        built = None
     if built is not None and pallas_tileable(built[0]):
-        program, out_masks = built
+        program, out_masks, slots = built
+        if slots:
+            vf = _stateful_vf(program, tuple(out_masks), in_names, out_names)
+            init_state = {"regs": np.array(
+                [module.actors[m].impl.initial_state[k] for m, k in slots],
+                np.float32)}
+        else:
+            def vf(state, ins, _prog=program, _masks=tuple(out_masks)):
+                vals = fused_stream([ins[p][0] for p in in_names], _prog)
+                return state, {
+                    o: (v, ins[m][1])
+                    for o, v, m in zip(out_names, vals, _masks)
+                }
 
-        def vf(state, ins, _prog=program, _masks=tuple(out_masks)):
-            vals = fused_stream([ins[p][0] for p in in_names], _prog)
-            return state, {
-                o: (v, ins[m][1]) for o, v, m in zip(out_names, vals, _masks)
-            }
-
+            init_state = {}
         codegen = "pallas"
-        init_state: Dict = {}
     else:
         program = None
         vf = _composed_vf(module, order, b_ins, b_outs, internal)
@@ -410,6 +493,7 @@ def build_fused(
         # flat-megastep gate reads it to size (k, block) chunk stacks against
         # the program's transform_unit
         actor.stream_program = program
+        actor.state_slots = tuple(slots)
     return FusedBuild(
         actor=actor,
         codegen=codegen,

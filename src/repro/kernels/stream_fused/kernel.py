@@ -30,9 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.graph import GraphError
-from repro.kernels.stream_fused.ops import row_width, transform_unit
+from repro.kernels.stream_fused.ops import LANES, row_width, transform_unit
 from repro.kernels.stream_fused.ref import apply_op
 
 _SUBLANES = 8
@@ -56,14 +57,9 @@ def _block_matrix(op, width: int) -> np.ndarray:
     return np.kron(np.eye(width // m.shape[0], dtype=np.float32), m)
 
 
-def _stream_kernel(x_ref, *rest, program):
-    # rest = (*matrix_refs, o_ref): the block-diagonal transform matrices
-    # ride in as operands because Pallas kernels may not capture array
-    # constants.
-    matrix_refs, o_ref = rest[:-1], rest[-1]
-    regs = [None] * program.n_regs
-    for i in range(program.n_inputs):
-        regs[i] = x_ref[i]
+def _run_ops(regs, program, matrix_refs, delay=None):
+    """The op list over the register file, in place; ``delay(op, x)`` gives
+    a delay op's output (stateful programs only)."""
     bi = 0
     for op in program.ops:
         if op.kind in ("matmul8", "perm"):
@@ -73,12 +69,92 @@ def _stream_kernel(x_ref, *rest, program):
                 preferred_element_type=jnp.float32,
             )
             bi += 1
+        elif op.kind == "delay":
+            regs[op.out] = delay(op, regs[op.ins[0]])
         else:
             regs[op.out] = apply_op(
                 op.kind, op.params, [regs[j] for j in op.ins]
             )
+
+
+def _stream_kernel(x_ref, *rest, program):
+    # rest = (*matrix_refs, o_ref): the block-diagonal transform matrices
+    # ride in as operands because Pallas kernels may not capture array
+    # constants.
+    matrix_refs, o_ref = rest[:-1], rest[-1]
+    regs = [None] * program.n_regs
+    for i in range(program.n_inputs):
+        regs[i] = x_ref[i]
+    _run_ops(regs, program, matrix_refs)
     for j, r in enumerate(program.outputs):
         o_ref[j] = regs[r]
+
+
+def _max_all(v):
+    """(rows, W) -> (1, 1) maximum, one axis at a time."""
+    return jnp.max(jnp.max(v, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _stateful_kernel(x_ref, s_ref, c_ref, *rest, program, tile_tokens):
+    """One row tile of a stateful program.  The grid walks a call's tiles
+    in order ("arbitrary"), and the (2, S) carry block stays resident
+    across them, written back once: lane j of row 0 holds register j (the
+    delay's input at the last valid token so far: the new state), row 1
+    the delay's input at the previous tile's last token, valid or not, so
+    that a tile's first output is the token before it, as in ``delay_ref``.
+    ``c_ref`` row m holds input m's valid-token count in every lane."""
+    matrix_refs, o_ref, so_ref = rest[:-2], rest[-2], rest[-1]
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        so_ref[...] = jnp.broadcast_to(s_ref[...], so_ref.shape)
+
+    carry = so_ref[...]
+    shape = x_ref.shape[1:]
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    base = i * tile_tokens
+    pos = base + row * shape[1] + col
+    slot_lane = jax.lax.broadcasted_iota(jnp.int32, carry.shape, 1)
+    carry_row = jax.lax.broadcasted_iota(jnp.int32, carry.shape, 0)
+    new_carry = [carry]
+
+    def delay(op, x):
+        slot, mask_input = op.params
+        # the slot's carries as (1, 1) vectors: a max over one live lane is
+        # exact (and keeps -0.0, which a masked sum would not)
+        lane = jnp.max(jnp.where(slot_lane == slot, carry, -jnp.inf),
+                       axis=1, keepdims=True)
+        s, prev = (
+            jnp.max(jnp.where(carry_row[:, :1] == r, lane, -jnp.inf),
+                    axis=0, keepdims=True)
+            for r in (0, 1)
+        )
+        a = pltpu.roll(x, 1, 1)          # a[r, c] = x[r, c - 1 mod W]
+        b = pltpu.roll(a, 1, 0)          # b[r, 0] = x[r - 1, W - 1]
+        y = jnp.where(pos == base, prev, jnp.where(col == 0, b, a))
+        last = jnp.minimum(c_ref[mask_input:mask_input + 1, :],
+                           base + tile_tokens) - 1
+        hit = pos == last
+        v = _max_all(jnp.where(hit, x, -jnp.inf))
+        found = _max_all(jnp.where(hit, 1.0, 0.0)) > 0.0
+        # b[0, 0] is the tile's last token
+        end = _max_all(jnp.where((row == 0) & (col == 0), b, -jnp.inf))
+        new_carry[0] = jnp.where(
+            slot_lane == slot,
+            jnp.where(carry_row == 0, jnp.where(found, v, s), end),
+            new_carry[0],
+        )
+        return y
+
+    regs = [None] * program.n_regs
+    for k in range(program.n_inputs):
+        regs[k] = x_ref[k]
+    _run_ops(regs, program, matrix_refs, delay)
+    for j, r in enumerate(program.outputs):
+        o_ref[j] = regs[r]
+    so_ref[...] = new_carry[0]
 
 
 def _rows_per_tile(rows: int, width: int, program) -> int:
@@ -95,8 +171,10 @@ def fused_stream_fwd(
     stack: jax.Array,  # (n_in, N) or (n_in, B, N) float32 wire stack
     program,
     *,
+    state=None,
+    counts=None,
     interpret: bool = False,
-) -> jax.Array:  # (n_out, N) / (n_out, B, N)
+):  # (n_out, N) / (n_out, B, N); with state, (that, new state)
     """One Pallas launch per call, batched or not.
 
     Leading axes between the wire axis and the token axis (B sessions, or
@@ -105,6 +183,8 @@ def fused_stream_fwd(
     of ``row_width(program)``; a block transform's blocks therefore never
     straddle a row (or a session, or a chunk) as long as ``N`` is a
     multiple of the transform's block size — otherwise the call is refused.
+    A stateful program takes its ``(n_state,)`` registers and per-input
+    valid ``counts`` and returns the new registers too (``_stateful_fwd``).
     """
     n_in, *lead, n = stack.shape
     unit = transform_unit(program)
@@ -114,6 +194,8 @@ def fused_stream_fwd(
             f"region's block transform size {unit}"
         )
     width = row_width(program)
+    if program.n_state:
+        return _stateful_fwd(stack, program, state, counts, width, interpret)
     n_pad = -n % width
     if n_pad:
         stack = jnp.pad(stack, [(0, 0)] * (stack.ndim - 1) + [(0, n_pad)])
@@ -142,3 +224,54 @@ def fused_stream_fwd(
     )(x, *matrices)
     out = out[:, :rows].reshape(n_out, *lead, n + n_pad)
     return out[..., :n] if n_pad else out
+
+
+def _stateful_fwd(stack, program, state, counts, width, interpret):
+    """A stateful program's call: every token of the call is one stream in
+    order, so the leading axes flatten *before* the row padding (padding
+    only at the end), and the grid walks row tiles in order, carrying each
+    register from tile to tile.  Under the serve batcher's vmap each
+    session lane is its own call (``pallas_call`` batches it as a parallel
+    grid axis)."""
+    n_in, *lead, n = stack.shape
+    tokens = math.prod(lead) * n
+    x = stack.reshape(n_in, tokens)
+    rows = -(-tokens // width)
+    fit = _rows_per_tile(rows, width, program)
+    tiles = -(-rows // fit)
+    # even tiles, each a multiple of 8 rows: the padding stays under 8 rows
+    # a tile where the stateless path would pad up to a whole tile
+    tile = rows if tiles == 1 else -(-rows // (tiles * _SUBLANES)) * _SUBLANES
+    x = jnp.pad(x, ((0, 0), (0, tiles * tile * width - tokens)))
+    x = x.reshape(n_in, tiles * tile, width)
+    lanes = -(-program.n_state // LANES) * LANES
+    s = jnp.pad(jnp.asarray(state, jnp.float32).reshape(-1),
+                (0, lanes - program.n_state)).reshape(1, lanes)
+    c = jnp.broadcast_to(
+        jnp.asarray(counts, jnp.int32).reshape(n_in, 1), (n_in, width)
+    )
+    matrices = [
+        jnp.asarray(_block_matrix(op, width))
+        for op in program.ops if op.kind in ("matmul8", "perm")
+    ]
+    n_out = len(program.outputs)
+    fixed = lambda i: (0, 0)  # noqa: E731 — resident across the tiles
+    out, new_state = pl.pallas_call(
+        functools.partial(_stateful_kernel, program=program,
+                          tile_tokens=tile * width),
+        grid=(tiles,),
+        in_specs=[pl.BlockSpec((n_in, tile, width), lambda i: (0, i, 0)),
+                  pl.BlockSpec((1, lanes), fixed),
+                  pl.BlockSpec((n_in, width), fixed)]
+        + [pl.BlockSpec(m.shape, fixed) for m in matrices],
+        out_specs=[pl.BlockSpec((n_out, tile, width), lambda i: (0, i, 0)),
+                   pl.BlockSpec((2, lanes), fixed)],
+        out_shape=[
+            jax.ShapeDtypeStruct((n_out, tiles * tile, width), jnp.float32),
+            jax.ShapeDtypeStruct((2, lanes), jnp.float32),
+        ],
+        interpret=interpret,
+        name="stream_fused",
+    )(x, s, c, *matrices)
+    out = out.reshape(n_out, -1)[:, :tokens].reshape(n_out, *lead, n)
+    return out, new_state[0, :program.n_state]

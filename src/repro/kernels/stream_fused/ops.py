@@ -6,6 +6,13 @@ A ``StreamProgram`` is the fusion pass's codegen target: a register file of
 output port.  The device step traces ``fused_stream`` once per region; on TPU
 it lowers to the Pallas kernel, on CPU to the jnp reference (which XLA fuses
 into one loop) — both compute the identical op sequence.
+
+A program may carry state: ``delay`` ops read and write ``n_state`` registers
+(one float32 each), which the call takes and returns beside the wires.  The
+call's tokens are one stream in order (every leading axis flattened), and a
+delay's new register is the last *valid* token of its wire, counted by the
+mask of the program input named in the op: the stagers fill each input's
+valid tokens as a prefix of the call, so padding never enters a register.
 """
 
 from __future__ import annotations
@@ -20,7 +27,8 @@ import jax.numpy as jnp
 from repro.kernels.stream_fused.ref import fused_stream_ref
 
 OP_KINDS = (
-    "affine", "clip", "matmul8", "axpy", "const", "min2", "max2", "perm"
+    "affine", "clip", "matmul8", "axpy", "const", "min2", "max2", "perm",
+    "delay",
 )
 
 
@@ -29,7 +37,8 @@ class StreamOp:
     kind: str                 # one of OP_KINDS
     ins: Tuple[int, ...]      # value registers read
     out: int                  # value register written
-    params: Tuple = ()        # static floats / arrays (matmul8 basis, perm idx)
+    params: Tuple = ()        # static floats / arrays (matmul8 basis, perm
+    #                           idx); a delay's (state register, mask input)
 
     def __str__(self) -> str:
         ps = ", ".join(
@@ -45,11 +54,14 @@ class StreamProgram:
     n_regs: int
     ops: Tuple[StreamOp, ...]
     outputs: Tuple[int, ...]  # registers of the fused output ports, in order
+    n_state: int = 0          # state registers the delay ops carry
 
     def __str__(self) -> str:
         body = "; ".join(str(op) for op in self.ops) or "passthrough"
         outs = ", ".join(f"r{i}" for i in self.outputs)
-        return f"stream({self.n_inputs} in, {self.n_regs} regs): {body} -> {outs}"
+        state = f", {self.n_state} state" if self.n_state else ""
+        return (f"stream({self.n_inputs} in, {self.n_regs} regs{state}): "
+                f"{body} -> {outs}")
 
 
 def transform_unit(program: StreamProgram) -> int:
@@ -97,7 +109,9 @@ def fused_stream(
     program: StreamProgram,
     *,
     use: str = "auto",  # "auto" | "pallas" | "ref"
-) -> List[jax.Array]:
+    state=None,   # (n_state,) float32 registers of a stateful program
+    counts=None,  # (n_inputs,) int32 valid tokens per input this call
+):
     """Run one fused region over a token block.
 
     ``auto`` picks the jnp reference on CPU (it compiles into the enclosing
@@ -107,14 +121,20 @@ def fused_stream(
     Inputs with a leading batch axis — ``(B, N)``, one row per server
     session — run as ONE kernel launch (the Pallas path flattens the token
     axis; the ref path is shape-polymorphic), with each row bit-identical to
-    a per-session dispatch (see ``ref.fused_stream_ref``).
+    a per-session dispatch (see ``ref.fused_stream_ref``).  A stateful
+    program instead reads all of a call's tokens as one stream (the serve
+    batcher vmaps it per session) and returns ``(outputs, new_state)``.
     """
     if use == "ref" or (use == "auto" and _on_cpu()):
-        return fused_stream_ref(inputs, program)
+        return fused_stream_ref(inputs, program, state, counts)
     from repro.kernels.stream_fused.kernel import fused_stream_fwd
 
     stack = jnp.stack([x.astype(jnp.float32) for x in inputs])
-    out = fused_stream_fwd(stack, program, interpret=_on_cpu())
+    out = fused_stream_fwd(stack, program, state=state, counts=counts,
+                           interpret=_on_cpu())
+    if program.n_state:
+        out, new_state = out
+        return [out[j] for j in range(len(program.outputs))], new_state
     return [out[j] for j in range(len(program.outputs))]
 
 
@@ -205,5 +225,6 @@ def fold(program: StreamProgram) -> StreamProgram:
                     changed = True
                     break
     return StreamProgram(
-        program.n_inputs, program.n_regs, tuple(ops), program.outputs
+        program.n_inputs, program.n_regs, tuple(ops), program.outputs,
+        program.n_state,
     )

@@ -12,6 +12,8 @@ the fused region is verifiably equivalent to the per-actor device path:
   const    jnp.full_like               rate seed (e.g. FIR acc = 0)
   min2/max2  jnp.minimum / jnp.maximum compare-exchange lanes
   perm     x.reshape(-1, P)[:, idx]    block reorder (e.g. JPEG zigzag descan)
+  delay    [s, x_0 .. x_{N-2}]         one-token delay through state register
+                                       s; s := x_{count-1} (the last valid)
 
 This module is also the device fallback: on CPU the fused region runs this
 reference inside the device-step ``jax.jit`` (XLA fuses the op chain), while
@@ -81,9 +83,23 @@ def apply_op(kind: str, params, ins: Sequence[jax.Array]) -> jax.Array:
     raise ValueError(f"unknown stream op {kind!r}")
 
 
-def fused_stream_ref(inputs: Sequence[jax.Array], program) -> List[jax.Array]:
+def delay_ref(x: jax.Array, s: jax.Array, count: jax.Array):
+    """The ``delay`` op over one call: the call's tokens flattened in order
+    are shifted by one, register ``s`` entering first; the new register is
+    the last valid token (the ``count``-th), or ``s`` when none is valid.
+    Pure data movement, so any evaluation of it is bitwise the same."""
+    flat = x.reshape(-1)
+    shifted = jnp.concatenate([s.reshape(1).astype(flat.dtype), flat[:-1]])
+    last = flat[jnp.maximum(count - 1, 0)]
+    return shifted.reshape(x.shape), jnp.where(count > 0, last, s)
+
+
+def fused_stream_ref(
+    inputs: Sequence[jax.Array], program, state=None, counts=None,
+):
     """Evaluate ``program`` over per-port input arrays; returns output arrays
-    in the program's declared output order.
+    in the program's declared output order (and the new ``(n_state,)``
+    registers after them when the program carries state).
 
     Inputs may be ``(N,)`` wires or ``(B, N)`` batched wires (one row per
     server session, or one row per megastep *chunk* — the ``(k, block)``
@@ -95,9 +111,19 @@ def fused_stream_ref(inputs: Sequence[jax.Array], program) -> List[jax.Array]:
     regs: List[jax.Array] = [None] * program.n_regs
     for i, x in enumerate(inputs):
         regs[i] = x
+    new_state = [None] * program.n_state
     for op in program.ops:
+        if op.kind == "delay":
+            slot, mask_input = op.params
+            regs[op.out], new_state[slot] = delay_ref(
+                regs[op.ins[0]], state[slot], counts[mask_input]
+            )
+            continue
         regs[op.out] = apply_op(op.kind, op.params, [regs[i] for i in op.ins])
-    return [regs[i] for i in program.outputs]
+    outs = [regs[i] for i in program.outputs]
+    if program.n_state:
+        return outs, jnp.stack(new_state)
+    return outs
 
 
 # ---------------------------------------------------------------------------

@@ -118,7 +118,7 @@ def snapshot_from_trace(
     device_time_ns = 0
     tok_in = tok_out = 0
     opened = closed = chunks = split = submitted = delivered = swaps = 0
-    pumped = delivered_blocks = 0
+    pumped = delivered_blocks = blocks = 0
     queue_peak = 0
     t_lo: Optional[float] = None
     t_hi = 0.0
@@ -157,6 +157,7 @@ def snapshot_from_trace(
                 lanes += ln
                 lanes_peak = max(lanes_peak, ln)
                 width += int(args.get("width", 0)) or ln
+                blocks += int(args.get("blocks", 0))
                 tok_in += int(args.get("tokens_in", 0))
                 device_time_ns += int(args.get("time_ns", 0))
             elif ev["name"] == "retire":
@@ -218,4 +219,5 @@ def snapshot_from_trace(
         swaps=swaps,
         tokens_pumped=pumped,
         tokens_delivered_blocks=delivered_blocks,
+        device_blocks=blocks,
     )

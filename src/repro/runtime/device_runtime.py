@@ -35,6 +35,7 @@ previous launch's state *future*.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -89,6 +90,15 @@ class DeviceProgram:
     donate: bool = True
     # the untraced megastep — what batched_megastep vmaps over
     raw_megastep: Callable = None
+    # per fused actor whose state is carried by masked stream ops, as its
+    # registers' vector ``{"regs": (n,)}``: the (member, state key) each
+    # register holds, so a transplant maps them to and from the members'
+    # own state by name.  Those ops take each register from the last valid
+    # token, so with any, the stagers fill a port's valid tokens as a
+    # prefix of the launch (a port group that stages a short chunk stages
+    # nothing more in that launch)
+    state_slots: Dict[str, Tuple[Tuple[str, str], ...]] = field(
+        default_factory=dict)
     # jitted megastep: (state, {in: (vals (k,block), mask (k,block))}) ->
     # (state', {out: (k,block)...}, idle); donates state like ``step``
     megastep: Callable = None
@@ -182,13 +192,30 @@ class DeviceProgram:
 
     @staticmethod
     def stack_states(states: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
-        """Per-session state trees -> one batched tree (lane order kept)."""
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+        """Per-session state trees -> one batched tree (lane order kept).
+        A tree with leaves stacks in one jitted call per width (compiled
+        when the width is first launched), not one eager op per leaf."""
+        if not jax.tree.leaves(states[0]):
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+        return _stack_states(list(states))
 
     @staticmethod
-    def unstack_state(batched: Dict[str, Any], lane: int) -> Dict[str, Any]:
-        """Extract one session's state tree from a batched tree."""
-        return jax.tree.map(lambda x: x[lane], batched)
+    def unstack_states(batched: Dict[str, Any], width: int) -> List[Dict]:
+        """Every lane's state tree of a batched tree, in one jitted call
+        per width when the tree has leaves."""
+        if not jax.tree.leaves(batched):
+            return [batched] * width
+        return _unstack_states(batched, width)
+
+
+@jax.jit
+def _stack_states(states):
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _unstack_states(batched, width):
+    return [jax.tree.map(lambda x: x[i], batched) for i in range(width)]
 
 
 def region_quantum(module: IRModule, actor_name: str) -> int:
@@ -366,12 +393,14 @@ def resolve_megastep_k(
     tokens, so k is floored to ``depth // (2*block)`` over the partition's
     boundary channels (depth inference sizes them for the requested k; an
     XCF-pinned shallower depth clamps here, flagged by the SB206 lint).
-    Stateful partitions are clamped to 1: the block scan that vectorizes a
-    stateful actor advances its state over *padding* positions too, so only
-    all-stateless partitions (fused stream regions, stateless vector fires)
-    keep megastep ≡ per-iteration bitwise on ragged tails.  Partitions with
-    no boundary inputs (on-device sources) have no staged work to amortize
-    and also stay at 1.
+    A partition holding state that masked stream ops do not carry is
+    clamped to 1: the block scan that vectorizes such an actor advances its
+    state over *padding* positions too.  State carried by a fused stream
+    region's ``delay`` ops (its ``state_slots``) takes each register
+    from the last valid token under the mask, so those regions, like
+    stateless partitions, keep megastep ≡ per-iteration bitwise on ragged
+    tails.  Partitions with no boundary inputs (on-device sources) have no
+    staged work to amortize and also stay at 1.
     """
     from repro.ir.passes import resolve_megastep
 
@@ -382,7 +411,8 @@ def resolve_megastep_k(
         return 1
     if not in_ports:
         return 1
-    if any(s for s in init_state.values()):
+    if any(s and not getattr(module.actors[a].impl, "state_slots", ())
+           for a, s in init_state.items()):
         return 1
     for ch in module.channels:
         if (ch.src in sub) == (ch.dst in sub):
@@ -586,6 +616,8 @@ def compile_partition(
         donate=donate,
         raw_megastep=raw_megastep,
         megastep=jitted_megastep,
+        state_slots={a: impls[a].state_slots for a in names
+                     if getattr(impls[a], "state_slots", ())},
     )
 
 

@@ -204,16 +204,19 @@ class PLink:
             self._phase("sync", self._sync_t0, self._sync_acc)
             self._sync_acc = 0
 
-    def _plan(self) -> Dict[str, int]:
+    def _plan(self, closed=()) -> Dict[str, int]:
         """Tokens stageable per boundary port right now: whole staging
         granules, lane-aligned across each destination actor's ports (a
         lockstep pair like a MAC's XIN/AIN must never skew — with
         device→device lanes the producing PLink runs on another thread, so
-        per-port counts are not snapshot-atomic), capped at one block."""
+        per-port counts are not snapshot-atomic), capped at one block;
+        the port groups in ``closed`` stage nothing."""
         block = self.program.block
         quanta = self.program.in_quanta
         plan: Dict[str, int] = {}
-        for keys in self.program.in_groups.values():
+        for group, keys in self.program.in_groups.items():
+            if group in closed:
+                continue
             g = min(
                 min(self.env.inputs[k].count(), block) // quanta[k]
                 for k in keys
@@ -235,7 +238,11 @@ class PLink:
         reused buffer can never leak a previous launch's tokens into the
         padding a stateful scan walks over.  Bulk drains go through the
         FIFO's low-copy ``peek_view``/``commit`` window when the ring
-        storage is contiguous, falling back to ``read``.
+        storage is contiguous, falling back to ``read``.  When the
+        program's state is carried by stream ops, a port group that stages
+        a short chunk stages nothing more this launch — a producer on
+        another thread may refill its FIFO meanwhile — so each port's valid
+        tokens stay a prefix of the launch.
         """
         device = self.program.device
         # Only a non-default device needs an explicit transfer: the jitted
@@ -256,8 +263,9 @@ class PLink:
             idx = (idx + 1) % _N_SLOTS
         slot = self._slots[idx]
         total = 0
+        closed = set()
         for j in range(self.k):
-            plan = self._plan()
+            plan = self._plan(closed)
             any_n = False
             for (a, p, _dt) in self.program.in_ports:
                 key = f"{a}.{p}"
@@ -279,6 +287,12 @@ class PLink:
                 mask[j, :n] = True
                 mask[j, n:] = False
                 total += n
+            if self.program.state_slots:
+                closed.update(
+                    group
+                    for group, keys in self.program.in_groups.items()
+                    if any(plan.get(k, 0) < self.program.block for k in keys)
+                )
             if not any_n and j + 1 < self.k:
                 # out of stageable granules: the remaining chunks are pure
                 # padding (zero values, all-False masks) — static (k, block)

@@ -150,6 +150,10 @@ class DeviceBatcher:
                         program.pack_lanes([self._pad()] * width)
                     ),
                 )
+                # the state handling a launch of this width runs, too:
+                # lanes go out of the launch and ride the next one
+                lanes = program.unstack_states(out[0], width)
+                out = program.stack_states(lanes)
             jax.block_until_ready(out)
         except Exception as e:
             raise DeviceCompileError(
@@ -187,12 +191,14 @@ class DeviceBatcher:
         return jax.device_put(tree, device)
 
     def _dispatched(self, sp: span, lanes: int, tokens_in: int,
-                    width: int) -> None:
+                    width: int, blocks: int) -> None:
         """Feed one dispatch to telemetry and the same numbers to the
         launch span's args, so replay is exact."""
         if self.telemetry is not None:
-            self.telemetry.device_dispatched(lanes, tokens_in, width=width)
-        sp.args.update(lanes=lanes, tokens_in=tokens_in, width=width)
+            self.telemetry.device_dispatched(lanes, tokens_in, width=width,
+                                             blocks=blocks)
+        sp.args.update(lanes=lanes, tokens_in=tokens_in, width=width,
+                       blocks=blocks)
 
     def _span(self, name: str, round_no: int) -> span:
         return span(
@@ -252,14 +258,16 @@ class DeviceBatcher:
                 else self.program.batched_step(width)
             )
             state_b, outs, _idle = batched_fn(state_b, ins_b)
-            for lane, st in enumerate(live):
+            lanes = self.program.unstack_states(state_b, width)
+            for st, lane_state in zip(live, lanes):
                 # rebind each rider to its lane's output-state future so it
                 # can ride the NEXT round before this one retires
-                st.state = self.program.unstack_state(state_b, lane)
+                st.state = lane_state
                 st.inflight += 1
             entry = _Round(live, outs, width=width, batched=True)
             self.inflight.append(entry)
-            self._dispatched(sp, len(live), tokens, width)
+            self._dispatched(sp, len(live), tokens, width,
+                             sum(st.blocks_staged for st in live))
             entry.t_launch_ns = time.perf_counter_ns() - t0
         return len(live)
 
@@ -290,7 +298,7 @@ class DeviceBatcher:
                 st.inflight += 1
                 entry = _Round([st], outs, width=1, batched=False)
                 self.inflight.append(entry)
-                self._dispatched(sp, 1, tokens, 1)
+                self._dispatched(sp, 1, tokens, 1, st.blocks_staged)
                 entry.t_launch_ns = time.perf_counter_ns() - t0
             launched += 1
         return launched

@@ -41,6 +41,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.actor_machine import ActorMachine, BasicController, PortEnv
@@ -369,6 +371,7 @@ class DeviceStage:
         }
         self.inflight = 0  # rounds this stage is riding right now
         self.tokens_staged = 0
+        self.blocks_staged = 0  # chunks the last stage() filled
         self.tokens_retired = 0
         # megastep: payloads are (k, block) chunk stacks when the program
         # runs k>1 repetition-vector iterations per launch
@@ -388,12 +391,15 @@ class DeviceStage:
         """Riding at least one in-flight round (legacy name)."""
         return self.inflight > 0
 
-    def _plan(self) -> Dict[str, int]:
+    def _plan(self, closed=()) -> Dict[str, int]:
         """Tokens stageable per boundary port right now (whole granules,
-        lane-aligned across each actor's ports, capped at one block)."""
+        lane-aligned across each actor's ports, capped at one block),
+        leaving out the port groups in ``closed``."""
         block = self.program.block
         plan: Dict[str, int] = {}
-        for _actor, keys in self.groups.items():
+        for group, keys in self.groups.items():
+            if group in closed:
+                continue
             g = min(
                 min(self.in_eps[k].count(), block) // self.quantum[k]
                 for k in keys
@@ -412,14 +418,20 @@ class DeviceStage:
         buffers; None when nothing to do.  Riding an in-flight round does
         NOT block staging the next one — the continuous batcher chains
         rounds through the device-state future, so a session streams
-        back-to-back launches without a drain barrier."""
+        back-to-back launches without a drain barrier.  ``blocks_staged``
+        counts the chunks that took tokens.  When the program's state is
+        carried by stream ops, a port group that stages a short chunk
+        stages nothing more this launch, so each port's valid tokens stay a
+        prefix of the launch."""
         plan = self._plan()
         if not plan:
             return None
-        total = 0
+        block = self.program.block
+        total = blocks = 0
+        closed = set()
         for j in range(self.k):
             if j > 0:
-                plan = self._plan()
+                plan = self._plan(closed)
             for key in self.quantum:  # every in-port appears in the payload
                 arr, mask = self._bufs[key]
                 row_a = arr[j] if self.k > 1 else arr
@@ -442,6 +454,12 @@ class DeviceStage:
                 row_m[:n] = True
                 row_m[n:] = False
                 total += n
+            blocks += bool(plan)
+            if self.program.state_slots:
+                closed.update(
+                    group for group, keys in self.groups.items()
+                    if any(plan.get(k, 0) < block for k in keys)
+                )
             if not plan and j + 1 < self.k:
                 for arr, mask in self._bufs.values():
                     arr[j + 1:] = 0
@@ -449,6 +467,7 @@ class DeviceStage:
                 break
         staged = {key: self._bufs[key] for key in self.quantum}
         self.tokens_staged += total
+        self.blocks_staged = blocks
         return staged
 
     def retire(self, outs) -> int:
@@ -779,13 +798,28 @@ class SessionPipeline:
 # -- device-state transplant across placements ------------------------------
 
 
+def _host_value(leaf):
+    """A device state leaf as the host holds it: a Python scalar for a
+    0-d leaf (what a host actor's state holds, so a degraded actor goes on
+    computing as the host does), else a numpy array."""
+    arr = np.asarray(jax.device_get(leaf))
+    return arr.item() if arr.ndim == 0 else arr
+
+
 def _flatten_device_state(stage: DeviceStage) -> Dict[str, Dict]:
-    """Per-member view of the device state, undoing fusion grouping."""
+    """Per-member view of the device state, undoing fusion grouping (a
+    stateful stream region's registers go back to their members' state
+    keys), with host values as leaves."""
     flat: Dict[str, Dict] = {}
     fused = stage.program.fused or {}
-    for actor, st in stage.state.items():
+    slots = stage.program.state_slots
+    for actor, st in jax.tree.map(_host_value, stage.state).items():
         members = fused.get(actor)
-        if members and set(st) == set(members):
+        if actor in slots:
+            flat.update({m: {} for m in members})
+            for (m, k), v in zip(slots[actor], st["regs"]):
+                flat[m][k] = v.item()
+        elif members and set(st) == set(members):
             flat.update({m: dict(s) for m, s in st.items()})
         else:
             flat[actor] = st
@@ -794,18 +828,31 @@ def _flatten_device_state(stage: DeviceStage) -> Dict[str, Dict]:
 
 def _transplant_device_state(program, init, carry: Dict[str, Dict]):
     """Rebuild a device-state tree from carried per-member state where the
-    actor names (and state keys) still match; everything else reinitializes."""
+    actor names (and state keys) still match; everything else reinitializes.
+    Carried leaves take the dtype of the leaves they replace."""
+
+    def like(value, leaf):
+        return jnp.asarray(np.asarray(value, dtype=np.asarray(leaf).dtype))
+
+    def take(st: Dict, old: Dict) -> Dict:
+        if set(old) != set(st):
+            return st
+        return {k: like(old[k], st[k]) for k in st}
+
     fused = program.fused or {}
     state = {}
     for actor, st in init.items():
         members = fused.get(actor)
-        if members and set(st) == set(members):
+        if actor in program.state_slots:
+            regs = np.array(st["regs"])
+            for j, (m, k) in enumerate(program.state_slots[actor]):
+                if k in carry.get(m, {}):
+                    regs[j] = carry[m][k]
+            state[actor] = {"regs": like(regs, st["regs"])}
+        elif members and set(st) == set(members):
             state[actor] = {
-                m: carry.get(m, st[m])
-                if set(carry.get(m, st[m])) == set(st[m]) else st[m]
-                for m in st
+                m: take(st[m], carry.get(m, st[m])) for m in st
             }
         else:
-            old = carry.get(actor, st)
-            state[actor] = old if set(old) == set(st) else st
+            state[actor] = take(st, carry.get(actor, st))
     return state

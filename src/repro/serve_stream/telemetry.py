@@ -48,6 +48,7 @@ class TelemetrySnapshot:
     swaps: int                                   # XCF hot-swaps in the window
     tokens_pumped: int = 0                       # admission queues -> ingress FIFOs
     tokens_delivered_blocks: int = 0             # of tokens_delivered, as array blocks
+    device_blocks: int = 0                       # blocks the lanes ran, summed
 
     @property
     def mean_batch(self) -> float:
@@ -94,7 +95,7 @@ class ServerTelemetry:
             chunks_submitted=0, chunks_split=0,
             tokens_submitted=0, tokens_delivered=0,
             queue_peak=0, swaps=0, tokens_pumped=0,
-            tokens_delivered_blocks=0,
+            tokens_delivered_blocks=0, device_blocks=0,
         )
 
     # -- recording (engine thread + admission-side client threads) -----------
@@ -118,12 +119,17 @@ class ServerTelemetry:
                 )
 
     def device_dispatched(
-        self, lanes: int, tokens_in: int, time_ns: int = 0, width: int = 0
+        self, lanes: int, tokens_in: int, time_ns: int = 0, width: int = 0,
+        blocks: int = 0,
     ) -> None:
+        """One launch: ``blocks`` sums, over its real lanes, the blocks
+        (megastep chunks) each lane staged tokens into — the megastep depth
+        in effect is ``device_blocks / device_lanes``."""
         with self._lock:
             for d in (self._win, self.totals):
                 d["device_dispatches"] += 1
                 d["device_lanes"] += lanes
+                d["device_blocks"] += blocks
                 d["device_width"] += width or lanes
                 if lanes > d["lanes_peak"]:
                     d["lanes_peak"] = lanes
@@ -196,6 +202,7 @@ class ServerTelemetry:
                     "chunks_submitted", "chunks_split", "tokens_submitted",
                     "tokens_delivered", "queue_peak", "swaps",
                     "tokens_pumped", "tokens_delivered_blocks",
+                    "device_blocks",
                 )
             },
         )

@@ -149,3 +149,32 @@ def test_torn_transform_block_is_refused():
             lambda s: fused_stream_fwd(s, PROGRAMS["perm"]),
             jax.ShapeDtypeStruct((1, 100), np.float32),
         )
+
+
+def _fir_program(taps=29):
+    """The stateful FIR region as fusion emits it: seed, then per tap a
+    delay through its register and an axpy."""
+    ops = [StreamOp("const", (0,), 1, (0.0,))]
+    x, acc, n = 0, 1, 2
+    for t in range(taps):
+        ops.append(StreamOp("delay", (x,), n, (t, 0)))
+        ops.append(StreamOp("axpy", (x, acc), n + 1, (0.01 * (t + 1),)))
+        x, acc, n = n, n + 1, n + 2
+    return StreamProgram(1, n, tuple(ops), (acc, x), n_state=taps)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_stateful_stream_kernel_compiles_for_v5e(shape, one_chip):
+    prog = _fir_program()
+    lanes = 32
+    full = (lanes, prog.n_inputs) + SHAPES[shape](N)
+
+    def call(x, state, counts):  # one lane per session, as the batcher vmaps
+        return jax.vmap(lambda v, s, c: fused_stream_fwd(
+            v, prog, state=s, counts=c))(x, state, counts)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            ((full, jnp.float32), ((lanes, prog.n_state), jnp.float32),
+             ((lanes, 1), jnp.int32))]
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
