@@ -118,7 +118,7 @@ def snapshot_from_trace(
     device_time_ns = 0
     tok_in = tok_out = 0
     opened = closed = chunks = split = submitted = delivered = swaps = 0
-    pumped = delivered_blocks = blocks = 0
+    pumped = pumped_blocks = delivered_blocks = blocks = 0
     queue_peak = 0
     t_lo: Optional[float] = None
     t_hi = 0.0
@@ -194,6 +194,7 @@ def snapshot_from_trace(
                 swaps += 1
             elif ev["name"] == "pump":
                 pumped += int(args.get("tokens", 0))
+                pumped_blocks += int(args.get("blocks", 0))
 
     if seconds is None:
         seconds = 0.0 if t_lo is None else max(t_hi - t_lo, 0.0) / 1e6
@@ -220,4 +221,5 @@ def snapshot_from_trace(
         tokens_pumped=pumped,
         tokens_delivered_blocks=delivered_blocks,
         device_blocks=blocks,
+        tokens_pumped_blocks=pumped_blocks,
     )

@@ -118,6 +118,22 @@ class RingFifo:
         self._r_loc += n
         self._sync_now()
 
+    def read_blocks(self, n: int) -> List[List[Any]]:
+        """Consume ``n`` tokens as the ring's contiguous slices, oldest
+        first: one list, or two when the window wraps (the ``ArrayFifo``
+        call of the same name, for readers of either kind)."""
+        assert self.count() >= n, (
+            f"{self.name}: read_blocks({n}) with {self.count()}"
+        )
+        i0 = self._r_loc % self.capacity
+        head = self.capacity - i0
+        if n <= head:
+            parts = [self._buf[i0:i0 + n]]
+        else:
+            parts = [self._buf[i0:], self._buf[:n - head]]
+        self.commit(n)
+        return parts
+
     # ---- writer API ----------------------------------------------------------------
     def space(self) -> int:
         if self._guard is not None:
@@ -228,6 +244,12 @@ class ArrayFifo:
     def peek(self, n: int):
         import numpy as np
 
+        parts = self.peek_blocks(n)
+        return parts[0] if len(parts) == 1 else np.concatenate(parts)
+
+    def peek_blocks(self, n: int) -> List[Any]:
+        """The next ``n`` tokens as the blocks (or block slices) that hold
+        them, oldest first, without consuming them."""
         assert self.count() >= n, f"{self.name}: peek({n}) with {self.count()}"
         parts = []
         got = 0
@@ -239,7 +261,7 @@ class ArrayFifo:
             head = 0
             if got == n:
                 break
-        return parts[0] if len(parts) == 1 else np.concatenate(parts)
+        return parts
 
     def peek_view(self, n: int):
         """The next ``n`` tokens as a genuinely zero-copy numpy view into
@@ -329,6 +351,12 @@ class ReaderEndpoint:
 
     def commit(self, n: int) -> None:
         return self.fifo.commit(n)
+
+    def read_blocks(self, n: int):
+        """Consume ``n`` tokens as the FIFO's own contiguous pieces: numpy
+        block slices from an ``ArrayFifo``, list slices from a
+        ``RingFifo``."""
+        return self.fifo.read_blocks(n)
 
 
 class WriterEndpoint:

@@ -48,6 +48,7 @@ from repro.serve_stream.session import (
     ServeError,
     SessionPipeline,
     StreamSession,
+    device_fed_ingress,
 )
 from repro.serve_stream.telemetry import ServerTelemetry
 
@@ -343,6 +344,7 @@ class StreamServer:
             session = StreamSession(
                 sid, self, self.ingress_ports, self.egress_ports,
                 self.admission_depth,
+                array_ingress=device_fed_ingress(self._program.module),
             )
             session.pipeline = self._build_pipeline(session)
             self._sessions.append(session)
@@ -544,16 +546,21 @@ class StreamServer:
             # session — with the captured traceback delivered to its
             # client — and the engine keeps serving everyone else.
             with span(rec, "engine", "engine", "pump", round=r) as sp:
+                pumped = blocks = 0
                 if not swapping:
                     for s in active:
-                        moved += self._guarded(
+                        n, nb = self._guarded(
                             s, s.pipeline.pump, "admission pump",
                             self.telemetry,
-                        )
-                if moved:
-                    sp.args["tokens"] = moved  # replays tokens_pumped
+                        ) or (0, 0)
+                        pumped += n
+                        blocks += nb
+                if pumped:
+                    sp.args["tokens"] = pumped  # replays tokens_pumped
+                    sp.args["blocks"] = blocks  # ... and tokens_pumped_blocks
                     with self._wake:  # free space -> unblock submitters
                         self._wake.notify_all()
+                moved += pumped
 
             # 2) host actors
             with span(rec, "engine", "engine", "host", round=r):

@@ -54,10 +54,13 @@ import numpy as np
 from repro import checkpoint as ckpt
 from repro.core.xcf import XCF
 from repro.observability.trace_profile import authored_channel_key
+from repro.runtime.fifo import ArrayFifo
 from repro.serve_stream.session import (
     ServeError,
     StreamSession,
     _flatten_device_state,
+    _write_tokens,
+    device_fed_ingress,
 )
 
 KIND = "streamserve/v1"
@@ -110,16 +113,19 @@ def _obj_arr(values: List) -> np.ndarray:
     return arr
 
 
-def _result_arr(buf) -> np.ndarray:
-    """One port's delivered tokens: one numeric array when every chunk is a
-    numeric block of one dtype (a device-fed sink), else the exact-type
-    object array."""
-    chunks = buf.chunks
+def _tokens_arr(chunks) -> np.ndarray:
+    """Tokens held as chunks (a device-fed port's delivered blocks, an
+    array admission queue's blocks): one numeric array when every chunk is
+    a numeric block of one dtype, else the exact-type object array (a
+    block's tokens as the Python scalars a host actor would see)."""
     if all(isinstance(c, np.ndarray) for c in chunks):
         dtypes = {c.dtype for c in chunks}
         if len(dtypes) == 1 and dtypes.pop().kind in "biuf":
             return np.concatenate(chunks)
-    return _obj_arr(list(buf))
+    return _obj_arr([
+        t for c in chunks
+        for t in (c.tolist() if isinstance(c, np.ndarray) else c)
+    ])
 
 
 def _host_view(state: Dict) -> Dict:
@@ -154,16 +160,19 @@ def snapshot_server(server) -> Tuple[Dict[str, np.ndarray], Dict]:
             "in_pipeline": 0,
         }
         for port, vals in s.results.items():
-            tree[f"s{s.sid}/result/{port}"] = _result_arr(vals)
+            tree[f"s{s.sid}/result/{port}"] = _tokens_arr(vals.chunks)
         if not s.finished.is_set() and p is not None:
             # admission residue: peek, never consume — a checkpoint must
             # not perturb the stream it snapshots
             queued = 0
             for port, q in s.queues.items():
                 q.snapshot_reader()
-                toks = list(q.peek(q.count()))
-                queued += len(toks)
-                tree[f"s{s.sid}/queue/{port}"] = _obj_arr(toks)
+                n = q.count()
+                queued += n
+                tree[f"s{s.sid}/queue/{port}"] = _tokens_arr(
+                    q.peek_blocks(n) if isinstance(q, ArrayFifo)
+                    else [q.peek(n)]
+                )
             m["queued"] = queued
             # FIFO residue by authored channel key (fusion renames lowered
             # keys per placement; authored keys survive recompilation)
@@ -283,6 +292,7 @@ def recover(
             s = StreamSession(
                 sid, server, server.ingress_ports, server.egress_ports,
                 server.admission_depth,
+                array_ingress=device_fed_ingress(server.program.module),
             )
             s.closed = m["closed"]
             s.error = m.get("error")
@@ -306,7 +316,9 @@ def recover(
                 for port, q in s.queues.items():
                     arr = flat.get(f"s{sid}/queue/{port}")
                     if arr is not None and arr.size:
-                        q.write(arr.tolist())
+                        _write_tokens(
+                            q, arr.tolist() if arr.dtype == object else arr
+                        )
                         q.publish_writer()
                 carry: Dict[str, Dict] = {}
                 for name in m.get("host_actors", ()):

@@ -10,7 +10,10 @@ The pipeline is the serve-mode reading of the lowered module (Fig. 6):
 
   * **source actors** (no input ports) are *not* instantiated — in serve
     mode the client IS the source, so each source's output channel becomes
-    an ingress FIFO pumped from the session's admission queue;
+    an ingress FIFO pumped from the session's admission queue; a source
+    that feeds a device partition gets an ``ArrayFifo`` for both, so the
+    client's tokens stay numpy blocks from ``submit`` to the launch's
+    staging buffer;
   * **sink actors** (no output ports) are *not* instantiated — their input
     channels become egress FIFOs drained into ``session.output(port)``, a
     ``DeliveredTokens`` buffer; a sink fed by a device partition gets an
@@ -35,11 +38,12 @@ back), so a session's outputs are bit-identical to a sequential
 
 from __future__ import annotations
 
+import array
 import bisect
 import math
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Collection, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +73,63 @@ class DeviceCompileError(ServeError):
 
 class AdmissionFull(ServeError):
     """Non-blocking submit against a full admission queue."""
+
+
+def device_fed_ingress(module: IRModule) -> Set[str]:
+    """Source actors whose channel feeds a device partition.  In serve mode
+    their admission queues and ingress FIFOs are ``ArrayFifo``s; every
+    other source's are ``RingFifo``s of Python objects, which host actors
+    consume."""
+    hw_of = module.hw_assignment()
+    return {
+        ch.src for ch in module.channels
+        if ch.dst in hw_of and ch.src not in hw_of
+        and not module.actors[ch.src].inputs
+    }
+
+
+def _token_block(values) -> np.ndarray:
+    """A submission as one numpy block: a private copy (a client that
+    reuses its buffer cannot change queued tokens) with the dtype numpy
+    infers, so nothing is rounded before staging casts to the port's
+    dtype.  A list of Python floats converts through ``array.array``,
+    which gives the same float64 array faster."""
+    if type(values) is list and values and type(values[0]) is float:
+        try:
+            return np.frombuffer(array.array("d", values), np.float64)
+        except (TypeError, OverflowError):
+            pass  # not all numbers: numpy infers the dtype
+    block = np.array(values)
+    if block.ndim == 0:  # an iterator rather than a sequence
+        block = np.array(list(values))
+    return block
+
+
+def _write_tokens(fifo, tokens) -> None:
+    """Write tokens into a FIFO of either kind, converting only where the
+    kinds differ: an ``ArrayFifo`` takes numpy blocks (a sequence becomes
+    one, as at submit), a ``RingFifo`` Python objects (a block's
+    ``tolist()``)."""
+    if isinstance(fifo, ArrayFifo):
+        if not isinstance(tokens, np.ndarray):
+            tokens = _token_block(tokens)
+    elif isinstance(tokens, np.ndarray):
+        tokens = tokens.tolist()
+    fifo.write(tokens)
+
+
+def _stage_into(row: np.ndarray, part) -> None:
+    """Copy one piece of a boundary FIFO into a staging row slice, cast to
+    the port's dtype as ``np.asarray(tokens, dtype)`` casts the Python
+    scalars the tokens were: host tokens (a list slice) convert one by
+    one; an integer block bound for a non-integer row goes through
+    float64, as a Python int converts through a C double."""
+    if not isinstance(part, np.ndarray):
+        row[:] = np.asarray(part, dtype=row.dtype)
+    elif part.dtype.kind in "iu" and row.dtype.kind not in "biu":
+        row[:] = part.astype(np.float64)
+    else:
+        row[:] = part
 
 
 class DeliveredTokens:
@@ -186,6 +247,7 @@ class StreamSession:
         ingress: Sequence[str],
         egress: Sequence[str],
         admission_depth: int,
+        array_ingress: Collection[str] = (),
     ):
         self.sid = sid
         self._server = server
@@ -195,9 +257,11 @@ class StreamSession:
         # (submit), the engine thread owns the reader (pump) — so this MUST
         # use the deferred snapshot/publish protocol.  deferred=False's
         # _sync_now republishes *both* counters and is only safe when one
-        # thread owns both endpoints.
-        self.queues: Dict[str, RingFifo] = {
-            name: RingFifo(
+        # thread owns both endpoints.  A port in ``array_ingress`` (its
+        # source feeds a device partition) queues numpy blocks; the others
+        # queue Python objects for host actors.
+        self.queues: Dict[str, object] = {
+            name: (ArrayFifo if name in array_ingress else RingFifo)(
                 admission_depth, name=f"s{sid}:{name}", deferred=True
             )
             for name in ingress
@@ -239,6 +303,11 @@ class StreamSession:
         When the queue lacks space: ``block=True`` waits (engine drains
         it), ``block=False`` raises ``AdmissionFull`` unless the *entire*
         submission fits right now — the client's cue to slow down.
+
+        A port whose source feeds a device partition copies the submission
+        once, here on the client thread, into a numpy block of the dtype
+        numpy infers (Python floats float64, ints int64); staging casts it
+        to the port's dtype.
         """
         if self.closed:
             raise ServeError(f"session {self.sid}: submit after close()")
@@ -256,7 +325,10 @@ class StreamSession:
                 f"session {self.sid}: unknown ingress {port!r} "
                 f"(have {sorted(self.queues)})"
             ) from None
-        values = list(values)
+        values = (
+            _token_block(values) if isinstance(q, ArrayFifo)
+            else list(values)
+        )
         # TTFO stamps BEFORE any admission wait: the SLO clock starts when
         # the client handed us tokens, so queueing delay under backpressure
         # is part of what the histogram measures, not silently excluded
@@ -438,16 +510,10 @@ class DeviceStage:
                 row_m = mask[j] if self.k > 1 else mask
                 n = plan.get(key, 0)
                 if n:
-                    ep = self.in_eps[key]
-                    view = (
-                        ep.peek_view(n)
-                        if hasattr(ep, "peek_view") else None
-                    )
-                    if view is not None:
-                        row_a[:n] = np.asarray(view, dtype=arr.dtype)
-                        ep.commit(n)
-                    else:
-                        row_a[:n] = np.asarray(ep.read(n), dtype=arr.dtype)
+                    i = 0
+                    for part in self.in_eps[key].read_blocks(n):
+                        _stage_into(row_a[i:i + len(part)], part)
+                        i += len(part)
                 # zero the tail: reused buffers must never leak a previous
                 # launch's tokens into masked-off padding
                 row_a[n:] = 0
@@ -554,6 +620,7 @@ class SessionPipeline:
             pid: DeviceStage(device_programs[pid], module)
             for pid in sorted({hw_of[a] for a in devset})
         }
+        array_src = device_fed_ingress(module)
         self.fifos: Dict[Tuple, RingFifo] = {}     # channel key -> fifo
         self.ingress: Dict[str, RingFifo] = {}     # source name -> fifo
         self.egress: List[Tuple[str, RingFifo]] = []  # (sink name, fifo)
@@ -564,10 +631,12 @@ class SessionPipeline:
             s_pid, d_pid = hw_of.get(ch.src), hw_of.get(ch.dst)
             if s_pid is not None and s_pid == d_pid:
                 continue  # compiled inside one device program
-            if s_pid is not None and (d_pid is not None or ch.dst in sinks):
-                # device -> device across partitions, or device -> the
-                # session's result buffer: numpy blocks, never per-token
-                # Python objects
+            if (
+                s_pid is not None and (d_pid is not None or ch.dst in sinks)
+            ) or ch.src in array_src:
+                # device -> device across partitions, device -> the
+                # session's result buffer, or the client -> a device
+                # partition: numpy blocks, never per-token Python objects
                 f = ArrayFifo(
                     ch.resolved_depth or default_depth,
                     name=f"s{session.sid}:{ch}",
@@ -612,8 +681,8 @@ class SessionPipeline:
                 residue = carry_fifos.get(
                     authored_channel_key(module, ch.key)
                 )
-                if residue:
-                    f.write(list(residue))
+                if residue is not None and len(residue):
+                    _write_tokens(f, residue)
                     f.publish_writer()
 
         # per-channel totals already folded into server telemetry — the
@@ -668,13 +737,18 @@ class SessionPipeline:
         }
 
     # -- engine-side round pieces -------------------------------------------
-    def pump(self, telemetry=None) -> int:
+    def pump(self, telemetry=None) -> Tuple[int, int]:
         """Admission queues -> ingress FIFOs (bounded by FIFO space).
+        Returns the tokens moved and how many of them moved as numpy blocks
+        (an ``ArrayFifo`` queue into an ``ArrayFifo`` ingress: no per-token
+        work).  A hot swap, repartition or degrade that moved the ingress's
+        consumer leaves the queue and the FIFO of different kinds; the
+        tokens convert here, at that one boundary.
 
         Engine-thread only; it owns the queues' reader endpoints, so each
         pump snapshots the client's published writes and publishes its own
         reads back (the deferred cross-thread FIFO protocol)."""
-        moved = 0
+        moved = blocks = 0
         for name, fifo in self.ingress.items():
             q = self.session.queues[name]
             quantum = self.pump_quantum[name]
@@ -682,12 +756,16 @@ class SessionPipeline:
             n -= n % quantum
             if n <= 0:
                 continue
-            fifo.write(list(q.read(n)))
+            for part in q.read_blocks(n):
+                _write_tokens(fifo, part)
             q.publish_reader()  # free the space for blocked submitters
+            as_blocks = isinstance(q, ArrayFifo) and isinstance(fifo, ArrayFifo)
+            nb = n if as_blocks else 0
             moved += n
+            blocks += nb
             if telemetry is not None:
-                telemetry.pumped(n, q.count())
-        return moved
+                telemetry.pumped(n, q.count(), nb)
+        return moved, blocks
 
     def host_round(self, telemetry=None) -> int:
         """Fire every host actor machine once (round-robin, like a thread
@@ -777,7 +855,7 @@ class SessionPipeline:
             carry.update(_flatten_device_state(stage))
         return carry
 
-    def carry_fifos(self) -> Dict[Tuple, List]:
+    def carry_fifos(self) -> Dict[Tuple, Sequence]:
         """Residual tokens per **authored** channel key (non-consuming).
 
         The fault-path complement of ``carry_state``: a forced swap cannot
@@ -786,12 +864,18 @@ class SessionPipeline:
         FIFOs is peeked here and written into the rebuilt pipeline's FIFOs
         (`carry_fifos=` on the constructor).  Device-internal channels hold
         no cross-launch tokens (SDF regions launch whole iterations), so
-        host FIFOs + admission queues are the complete token residue."""
-        out: Dict[Tuple, List] = {}
+        host FIFOs + admission queues are the complete token residue.
+        A client's tokens in an ``ArrayFifo`` ingress stay one numpy array,
+        which a rebuilt ``RingFifo`` ingress takes as Python scalars."""
+        ingress = set(self.ingress.values())
+        out: Dict[Tuple, Sequence] = {}
         for key, f in self.fifos.items():
             n = f.count()
             if n:
-                out[authored_channel_key(self.module, key)] = list(f.peek(n))
+                toks = f.peek(n)
+                out[authored_channel_key(self.module, key)] = (
+                    toks if f in ingress else list(toks)
+                )
         return out
 
 

@@ -49,6 +49,7 @@ class TelemetrySnapshot:
     tokens_pumped: int = 0                       # admission queues -> ingress FIFOs
     tokens_delivered_blocks: int = 0             # of tokens_delivered, as array blocks
     device_blocks: int = 0                       # blocks the lanes ran, summed
+    tokens_pumped_blocks: int = 0                # of tokens_pumped, as array blocks
 
     @property
     def mean_batch(self) -> float:
@@ -96,6 +97,7 @@ class ServerTelemetry:
             tokens_submitted=0, tokens_delivered=0,
             queue_peak=0, swaps=0, tokens_pumped=0,
             tokens_delivered_blocks=0, device_blocks=0,
+            tokens_pumped_blocks=0,
         )
 
     # -- recording (engine thread + admission-side client threads) -----------
@@ -162,13 +164,15 @@ class ServerTelemetry:
                 d["tokens_submitted"] += tokens
                 d["chunks_split"] += split
 
-    def pumped(self, tokens: int, depth: int) -> None:
-        """One admission pump of ``tokens`` leaving a queue ``depth`` deep:
-        both under one lock acquisition, since the engine thread pumps
-        every session every round while client threads submit."""
+    def pumped(self, tokens: int, depth: int, blocks: int = 0) -> None:
+        """One admission pump of ``tokens`` leaving a queue ``depth`` deep,
+        ``blocks`` of them moved as array blocks: all under one lock
+        acquisition, since the engine thread pumps every session every
+        round while client threads submit."""
         with self._lock:
             for d in (self._win, self.totals):
                 d["tokens_pumped"] += tokens
+                d["tokens_pumped_blocks"] += blocks
                 if depth > d["queue_peak"]:
                     d["queue_peak"] = depth
 
@@ -202,7 +206,7 @@ class ServerTelemetry:
                     "chunks_submitted", "chunks_split", "tokens_submitted",
                     "tokens_delivered", "queue_peak", "swaps",
                     "tokens_pumped", "tokens_delivered_blocks",
-                    "device_blocks",
+                    "device_blocks", "tokens_pumped_blocks",
                 )
             },
         )
